@@ -82,6 +82,7 @@ from repro.data.lsh_pipeline import (
 from repro.models import ModelConfig, forward, init_params, loss as lm_loss
 from repro.optim import SGD, AdaGrad, Adam, apply_updates
 from repro.train import Trainer, TrainerConfig
+from repro.launch.cache import enable_compile_cache
 
 RESULTS = os.path.join(os.path.dirname(__file__), "results")
 KEY = jax.random.PRNGKey(0)
@@ -847,6 +848,11 @@ def tab_multihost(quick: bool = False):
         LR, PARAM_KEY_SEED, build_pipeline, model_cfg)
     from repro.testing import ProcKill
 
+    if jax.default_backend() != "cpu":
+        # the two worker processes run on the CPU (one chip admits one
+        # process), so the in-process baseline must run there too
+        raise RuntimeError("tab_multihost compares CPU processes only: "
+                           "run it with JAX_PLATFORMS=cpu")
     steps = 20 if quick else 40
     warmup = 4
     sync_every = 5
@@ -917,13 +923,14 @@ def tab_multihost(quick: bool = False):
     reform_s = r0k["timings"].get("reform_to_first_step_s")
 
     ratio = us_2p / max(us_1p, 1e-9)
-    _row("tab_multihost_one_proc", us_1p, "2 shards, one process")
-    _row("tab_multihost_two_proc", us_2p, f"{ratio:.2f}x one-process")
+    _row("tab_multihost_one_proc", us_1p, "cpu, 2 shards, one process")
+    _row("tab_multihost_two_proc", us_2p,
+         f"cpu, {ratio:.2f}x one-process")
     _row("tab_multihost_reform", 0.0,
          f"{reform_s:.2f}s to first post-reform step" if reformed
          and reform_s is not None else "NOT REFORMED")
     out = {
-        "backend": jax.default_backend(),
+        "backend": "cpu",
         "quick": quick, "batch": 16, "n_corpus": 256,
         "steps_timed": steps, "warmup": warmup, "nprocs": 2,
         "sync_every": sync_every,
@@ -1580,6 +1587,7 @@ def main() -> None:
                     help="CI-sized problems/iterations")
     args = ap.parse_args()
     names = args.tables or list(TABLES)
+    enable_compile_cache()
 
     os.makedirs(RESULTS, exist_ok=True)
     print("name,us_per_call,derived")

@@ -19,9 +19,11 @@ from repro.models import ModelConfig, init_params
 from repro.optim import Adam
 from repro.train import Trainer, TrainerConfig, checkpoint as ckpt
 from repro.train.elastic import rescale_plan, restore_latest_valid_on_mesh
+from repro.launch.cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     cfg = ModelConfig(name="elastic-demo", n_layers=2, d_model=64,
                       n_heads=4, n_kv_heads=2, d_ff=128, vocab=256,
                       chunk=16, loss_chunk=32, dtype="float32",

@@ -32,9 +32,11 @@ from repro.core import (
 )
 from repro.data import make_regression
 from repro.optim import make_optimizer
+from repro.launch.cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=600,
                     help="training steps (600 reproduces the paper curve; "

@@ -38,6 +38,7 @@ from repro.models import (
     LMHeadIndex, SampledSoftmaxConfig, decode_step, init_cache, init_params,
     lsh_decode_step, prefill,
 )
+from repro.launch.cache import enable_compile_cache
 
 
 def _percentiles(ms):
@@ -48,6 +49,7 @@ def _percentiles(ms):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="zamba2_1_2b",
                     choices=configs.all_archs())

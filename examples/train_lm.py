@@ -91,6 +91,7 @@ from repro.models import (
 )
 from repro.optim import make_optimizer, schedules
 from repro.train import Trainer, TrainerConfig
+from repro.launch.cache import enable_compile_cache
 
 PRESETS = {
     "demo": dict(n_layers=4, d_model=128, n_heads=4, n_kv_heads=2,
@@ -102,6 +103,7 @@ PRESETS = {
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="demo", choices=list(PRESETS))
     ap.add_argument("--steps", type=int, default=200)

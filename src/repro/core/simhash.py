@@ -112,9 +112,13 @@ def compute_codes(
         x = x[None]
     if quadratic:
         # proj[h] = x^T M_h x  — implicit SRP over T(x)=vec(x x^T).
-        proj = jnp.einsum("nd,hde,ne->nh", x, projections, x)
+        proj = jnp.einsum("nd,hde,ne->nh", x, projections, x,
+                          precision=jax.lax.Precision.HIGHEST)
     else:
-        proj = x @ projections  # (n, L*K)
+        # HIGHEST: the same f32 projection the hashing kernels compute
+        # (TPU's default f32 matmul rounds inputs to bf16; CPU is exact)
+        proj = jnp.dot(x, projections,
+                       precision=jax.lax.Precision.HIGHEST)  # (n, L*K)
     bits = (proj >= 0).reshape(x.shape[0], l, k)
     codes = _pack_bits(bits, k)
     return codes[0] if squeeze else codes

@@ -86,6 +86,11 @@ class HealthMonitor:
         self.stale_refreshes = 0       # consecutive failed refreshes
         self.refresh_failures = 0      # lifetime failed refresh attempts
         self.recoveries = 0            # lifetime degraded -> healthy edges
+        self.refreshes = 0             # lifetime committed refreshes
+        # every exception a refresh, retry or recovery caught (and logged)
+        # instead of raising: a run that must not degrade checks it is 0.
+        self.caught_errors = 0
+        self.last_error = ""
         self._fallback_strikes = 0
         self._nonfinite_strikes = 0
         self._entered_fallback_step = 0
@@ -110,6 +115,7 @@ class HealthMonitor:
     # -- signals -------------------------------------------------------------
 
     def note_refresh_success(self, step: int):
+        self.refreshes += 1
         self.stale_refreshes = 0
         if self.state == STALE_INDEX:
             self._move(step, HEALTHY, "refresh recovered")
@@ -155,6 +161,12 @@ class HealthMonitor:
         else:
             self._nonfinite_strikes = 0
 
+    def note_caught(self, reason: str):
+        """An exception was caught on the refresh path (even one a retry
+        then recovered from)."""
+        self.caught_errors += 1
+        self.last_error = reason
+
     def note_recovered(self, step: int, reason: str = "index rebuilt"):
         self._move(step, HEALTHY, reason)
 
@@ -175,7 +187,10 @@ class HealthMonitor:
         return {
             "state": self.state,
             "stale_refreshes": self.stale_refreshes,
+            "refreshes": self.refreshes,
             "refresh_failures": self.refresh_failures,
+            "caught_errors": self.caught_errors,
+            "last_error": self.last_error,
             "recoveries": self.recoveries,
             "transitions": list(self.transitions),
         }

@@ -17,8 +17,8 @@ The paper's BERT recipe, integrated as a first-class pipeline feature:
 
 DEVICE-RESIDENT STEP PATH: the token corpus is uploaded to device ONCE
 at pipeline build (``self.store``, lane-padded for the kernel gather;
-committed via ``dist.sharding.shard_store_device`` — mesh-replicated
-under a single-controller mesh), and every ``next_batch`` /
+committed via ``dist.sharding.shard_store_device`` to one device of
+the shard's data-parallel group), and every ``next_batch`` /
 ``next_batch_multi`` is a single jitted on-device program
 (``core.sampler.sample_gather``): query hash -> fused bucket probe ->
 within-bucket draw -> token-row gather -> 1/(p·N) weight computation
@@ -160,6 +160,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import (
     EMPTY_CODE,
@@ -363,8 +364,10 @@ class LSHSampledPipeline:
         with the params current at refresh-launch time.
 
     ``store_device`` pins the device-resident token store (and hence all
-    per-step sampling compute) to a specific device — the sharded owner
-    passes each shard's DP-group device (``shard_store_device``).
+    per-step sampling compute, features and index) to one device — the
+    sharded owner passes each shard's DP-group device
+    (``shard_store_device``).  With params on a mesh, each embed chunk
+    runs on the mesh and its features come back to that device.
 
     Args:
       key: constructor PRNG key; ALL pipeline randomness derives from
@@ -542,9 +545,22 @@ class LSHSampledPipeline:
     # -- features -----------------------------------------------------------
 
     def _embed(self, chunk: jax.Array, params: Any) -> jax.Array:
-        if self._params_aware:
-            return self.feature_fn(params, chunk)
-        return self.feature_fn(chunk)
+        if not self._params_aware:
+            return self.feature_fn(chunk)
+        leaves = jax.tree.leaves(params)
+        sh = getattr(leaves[0], "sharding", None) if leaves else None
+        if isinstance(sh, NamedSharding):
+            # params span a mesh, the store sits on one device of it:
+            # embed on the mesh, keep the features beside the store
+            chunk = jax.device_put(chunk, NamedSharding(sh.mesh, P()))
+        return self._on_store(self.feature_fn(params, chunk))
+
+    def _on_store(self, x: jax.Array) -> jax.Array:
+        """``x`` on the store's device (params-derived values: features,
+        queries), so every per-shard program spans that one device."""
+        if self._store_device is None:
+            return x
+        return jax.device_put(x, self._store_device)
 
     def _normalize(self, f: jax.Array) -> jax.Array:
         return f / jnp.maximum(
@@ -754,6 +770,8 @@ class LSHSampledPipeline:
         """
         attempts = 1 + max(self.cfg.refresh_retries, 0)
         err = first_error
+        if first_error is not None:
+            self.health.note_caught(repr(first_error))
         for attempt in range(start_attempt, attempts):
             self._sleep_backoff(attempt)
             try:
@@ -763,6 +781,7 @@ class LSHSampledPipeline:
                         store, live, attempt))
             except Exception as e:       # noqa: BLE001 — any failure retries
                 err = e
+                self.health.note_caught(repr(e))
                 log.warning("refresh %d attempt %d failed: %r",
                             self._refresh_count, attempt, e)
                 continue
@@ -953,6 +972,7 @@ class LSHSampledPipeline:
         except Exception as e:           # noqa: BLE001
             log.warning("recovery rebuild failed at step %d: %r",
                         self._step, e)
+            self.health.note_caught(repr(e))
             self.health.refresh_failures += 1
             return False
         self.features, self.index = feats, idx
@@ -1010,6 +1030,7 @@ class LSHSampledPipeline:
             if "error" in box:
                 log.warning("in-flight refresh failed at teardown: %r",
                             box["error"])
+                self.health.note_caught(repr(box["error"]))
                 self.health.note_refresh_failure(
                     self._step, repr(box["error"]))
 
@@ -1419,7 +1440,7 @@ class LSHSampledPipeline:
             else self.query_fn()
         # family query augmentation: SRP normalises (bit-identical to
         # the pre-family pipeline), MIPS appends the zero coordinate.
-        return self.family.augment_query(q)
+        return self._on_store(self.family.augment_query(q))
 
     def _mark_dirty(self, indices: jax.Array):
         if self._track_dirty:
@@ -1455,6 +1476,19 @@ class LSHSampledPipeline:
             "last_fallback_rate": float(self._last_fallback),
         }
 
+    def draw_inputs(self, key: jax.Array, query: jax.Array):
+        """``(args, kwargs)`` of the ``sample_gather`` call that draws one
+        batch under ``key`` for the (augmented) ``query`` — what
+        ``next_batch`` runs, exposed so a caller can lower or replay the
+        exact sample program."""
+        return (key, self.index, self.features, query, self.store,
+                self.lsh), dict(
+            m=self.cfg.minibatch, example_offset=self.example_offset,
+            multiprobe=self.cfg.multiprobe, p_floor=self.cfg.p_floor,
+            normalize=self.cfg.normalize_weights,
+            use_pallas=self.cfg.use_pallas, interpret=self.cfg.interpret,
+            row_width=self.row_width, n_live=self._n_live_dev)
+
     def next_batch(self, query: Optional[jax.Array] = None
                    ) -> Dict[str, jax.Array]:
         """Draw one batch — a single jitted on-device program; ``query``
@@ -1467,15 +1501,9 @@ class LSHSampledPipeline:
         sub = self._tick()
         if self.health.state == UNIFORM_FALLBACK:
             return self._uniform_batch(sub, self.cfg.minibatch)
-        q = self._query() if query is None else query
-        gb = sample_gather(
-            sub, self.index, self.features, q, self.store, self.lsh,
-            m=self.cfg.minibatch, example_offset=self.example_offset,
-            multiprobe=self.cfg.multiprobe,
-            p_floor=self.cfg.p_floor,
-            normalize=self.cfg.normalize_weights,
-            use_pallas=self.cfg.use_pallas, interpret=self.cfg.interpret,
-            row_width=self.row_width, n_live=self._n_live_dev)
+        q = self._query() if query is None else self._on_store(query)
+        args, kw = self.draw_inputs(sub, q)
+        gb = sample_gather(*args, **kw)
         self._mark_dirty(gb.indices)
         self._accum_stats(gb)
         return {
@@ -1504,7 +1532,7 @@ class LSHSampledPipeline:
             big = self._uniform_batch(sub, c * m)
             return [{k: v[i * m:(i + 1) * m] for k, v in big.items()}
                     for i in range(c)]
-        qn = self.family.augment_query(queries)
+        qn = self._on_store(self.family.augment_query(queries))
         gb = sample_gather_batched(
             sub, self.index, self.features, qn, self.store, self.lsh,
             m=self.cfg.minibatch, example_offset=self.example_offset,
@@ -1532,12 +1560,10 @@ class ShardedLSHPipeline:
     shards (``example_shard_bounds``); shard s owns an independent
     ``LSHSampledPipeline`` keyed by ``fold_in(key, s)`` over its n_s
     examples, with its token store uploaded once and committed via
-    ``shard_store_device`` — NOTE: under a single-controller mesh that
-    placement is MESH-REPLICATED (the mesh-sharded model params force
-    every embed/sample computation to span the mesh; budget HBM for
-    every store on every device).  True per-DP-group store residency is
-    the multi-controller deployment, where each process constructs only
-    its own shard's pipeline.  Every global batch is the concatenation
+    ``shard_store_device`` to one device of DP group s: its features,
+    index and every draw live there (the embed alone spans the mesh, as
+    the params do), so each store is held once.  Every global batch is
+    the concatenation
     of equal-size per-shard sub-batches (minibatch must divide by
     n_shards), laid out so dim 0 slices map shard s's examples to DP
     group s under ``dist.sharding.batch_sharding`` — with a mesh the
@@ -1856,7 +1882,12 @@ class ShardedLSHPipeline:
         return {
             "state": self.health_state(),
             "stale_refreshes": max(s["stale_refreshes"] for s in per),
+            # refreshes every shard has committed
+            "refreshes": min(s["refreshes"] for s in per),
             "refresh_failures": sum(s["refresh_failures"] for s in per),
+            "caught_errors": sum(s["caught_errors"] for s in per),
+            "last_error": next((s["last_error"] for s in reversed(per)
+                                if s["last_error"]), ""),
             "recoveries": sum(s["recoveries"] for s in per),
             "transitions": [
                 (shard_id,) + tuple(t)

@@ -220,25 +220,31 @@ def host_local_mesh(axis_names=("data", "model")):
 # ---------------------------------------------------------------------------
 
 def shard_store_device(mesh, shard_id: int, n_shards: int):
-    """Placement for corpus shard ``shard_id``'s token/feature store.
+    """The one device that holds corpus shard ``shard_id``'s store,
+    features and index.
 
     The LGD pipeline uploads each shard's example store ONCE at build
     time; all per-step sampling, gathering and weighting then runs where
-    the data lives — no host round-trip.  Under a single-controller mesh
-    the store must be committed MESH-WIDE (replicated): the feature/query
-    hooks take the model params, which are sharded across the whole
-    mesh, and jit refuses inputs committed to mismatched device sets —
-    a store pinned to one device cannot feed a mesh-spanning embed.
-    (True per-DP-group residency is the multi-controller deployment,
-    where each process only constructs its own shard's pipeline and the
-    store never leaves the group's hosts; ``shard_id``/``n_shards``
-    stay in the signature for that path.)  Returns None without a mesh
+    the data lives — no host round-trip.  That device is the first
+    (addressable) one of the data-parallel group whose batch rows the
+    shard fills under ``batch_sharding``, so ``compose_sharded_batch``
+    adopts its sub-batch zero-copy and each store is held once, not on
+    every device.  Per-shard programs must span one device: a Pallas
+    TPU kernel inside a program partitioned over a mesh is refused.
+    The params-aware embed still spans the mesh (see
+    ``LSHSampledPipeline._embed``).  Returns None without a mesh
     (single-device hosts: the default device is the only choice).
     """
-    del shard_id, n_shards
     if mesh is None:
         return None
-    return NamedSharding(mesh, P())
+    rows = n_shards * data_axis_size(mesh)
+    first = shard_id * rows // n_shards      # the shard's first batch row
+    where = batch_sharding(mesh).addressable_devices_indices_map((rows,))
+    for dev, idx in where.items():
+        start, stop, _ = idx[0].indices(rows)
+        if start <= first < stop:
+            return dev
+    return next(iter(where))
 
 
 def compose_sharded_batch(parts, mesh):

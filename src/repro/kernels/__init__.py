@@ -1,6 +1,10 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels of the LGD hot path.
+
+Each kernel family is a package with ``kernel.py`` (the Pallas kernel),
+``ref.py`` (the pure-XLA oracle) and ``ops.py`` (the jitted wrapper that
+pads, dispatches and unpads).  ``tests/test_tpu_compile.py`` compiles
+every main-path kernel for a v5e; the interpret-mode tests pin parity.
+"""
 
 import jax
 

@@ -11,5 +11,4 @@ from .ref import (  # noqa: F401
 from .kernel import (  # noqa: F401
     bucket_probe_codes_pallas,
     bucket_probe_multi_pallas,
-    bucket_probe_pallas,
 )

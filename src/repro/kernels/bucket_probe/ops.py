@@ -17,13 +17,13 @@ import jax
 import jax.numpy as jnp
 
 from .. import round_up as _round_up
+from ..simhash.kernel import block_rows, pack_matrices, tile_projections
 from .kernel import (
-    DEFAULT_BB,
     DEFAULT_BL,
     DEFAULT_BN,
+    MAX_BB,
     bucket_probe_codes_pallas,
     bucket_probe_multi_pallas,
-    bucket_probe_pallas,
 )
 from .ref import (
     bucket_probe_codes_ref,
@@ -38,17 +38,50 @@ def _bias(codes_u32: jax.Array) -> jax.Array:
         codes_u32 ^ jnp.uint32(0x80000000), jnp.int32)
 
 
-def _blocks(b: int, l: int, n: int):
-    bb = min(DEFAULT_BB, _round_up(b, 8))
+def _blocks(b: int, l: int, n: int, d: int = 0):
+    """(BB, BL, BN) and the padded (B, L, N) they tile."""
+    bb = min(MAX_BB, block_rows(d) if d else MAX_BB, _round_up(b, 8))
     bl = min(DEFAULT_BL, l)
     bn = min(DEFAULT_BN, _round_up(n, 128))
-    return bb, bl, bn
+    return (bb, bl, bn), (_round_up(b, bb), _round_up(l, bl), _round_up(n, bn))
 
 
 def _pad_sc(sorted_codes: jax.Array, l_pad: int, n_pad: int) -> jax.Array:
     l, n = sorted_codes.shape
     sc = jnp.pad(sorted_codes, ((0, l_pad - l), (0, n_pad - n)))
     return _bias(sc)
+
+
+def _unblock(a: jax.Array, b: int, l: int) -> jax.Array:
+    """(L/BL, J, B_pad, BL) kernel counts -> (B, J, L)."""
+    n_lt, j, b_pad, bl = a.shape
+    return a.transpose(2, 1, 0, 3).reshape(b_pad, j, n_lt * bl)[:b, :, :l]
+
+
+def _check(q, w, sorted_codes, k, l):
+    if w.shape != (q.shape[1], l * k):
+        raise ValueError(
+            f"projections {w.shape} != (d={q.shape[1]}, L*K={l * k})")
+    if sorted_codes.shape[0] != l:
+        raise ValueError(
+            f"sorted_codes {sorted_codes.shape} has {sorted_codes.shape[0]} "
+            f"tables, expected L={l}")
+
+
+def _fused(q, w, sorted_codes, masks, k, l, interpret):
+    """(B, J, L) bounds through the fused hash + probe kernel."""
+    b, d = q.shape
+    n = sorted_codes.shape[1]
+    (bb, bl, bn), (b_pad, l_pad, n_pad) = _blocks(b, l, n, d)
+    lo, hi = bucket_probe_multi_pallas(
+        jnp.pad(q, ((0, b_pad - b), (0, 0))),
+        tile_projections(jnp.pad(w, ((0, 0), (0, (l_pad - l) * k))),
+                         k=k, bl=bl),
+        pack_matrices(k, bl),
+        _pad_sc(sorted_codes, l_pad, n_pad),
+        masks=tuple(masks), n_actual=n, block_b=bb, block_n=bn,
+        interpret=interpret)
+    return _unblock(lo, b, l), _unblock(hi, b, l)
 
 
 @partial(jax.jit, static_argnames=("k", "l", "use_pallas", "interpret"))
@@ -66,26 +99,12 @@ def bucket_probe(
     squeeze = q.ndim == 1
     if squeeze:
         q = q[None]
-    if w.shape != (q.shape[1], l * k):
-        raise ValueError(f"projections {w.shape} != (d={q.shape[1]}, L*K={l * k})")
-    if sorted_codes.shape[0] != l:
-        raise ValueError(f"sorted_codes {sorted_codes.shape} has {sorted_codes.shape[0]} tables, expected L={l}")
+    _check(q, w, sorted_codes, k, l)
     if not use_pallas:
         lo, hi = bucket_probe_ref(q, w, sorted_codes, k=k, l=l)
     else:
-        b, d = q.shape
-        _, n = sorted_codes.shape
-        bb, bl, bn = _blocks(b, l, n)
-        b_pad, l_pad, n_pad = (_round_up(b, bb), _round_up(l, bl),
-                               _round_up(n, bn))
-        lo, hi = bucket_probe_pallas(
-            jnp.pad(q, ((0, b_pad - b), (0, 0))),
-            jnp.pad(w, ((0, 0), (0, (l_pad - l) * k))),
-            _pad_sc(sorted_codes, l_pad, n_pad),
-            k=k, l=l_pad, n_actual=n, block_b=bb, block_l=bl, block_n=bn,
-            interpret=interpret,
-        )
-        lo, hi = lo[:b, :l], hi[:b, :l]
+        lo, hi = _fused(q, w, sorted_codes, (0,), k, l, interpret)
+        lo, hi = lo[:, 0], hi[:, 0]
     return (lo[0], hi[0]) if squeeze else (lo, hi)
 
 
@@ -114,35 +133,11 @@ def bucket_probe_multi(
     squeeze = q.ndim == 1
     if squeeze:
         q = q[None]
-    if w.shape != (q.shape[1], l * k):
-        raise ValueError(
-            f"projections {w.shape} != (d={q.shape[1]}, L*K={l * k})")
-    if sorted_codes.shape[0] != l:
-        raise ValueError(
-            f"sorted_codes {sorted_codes.shape} has {sorted_codes.shape[0]} "
-            f"tables, expected L={l}")
-    j = len(masks)
+    _check(q, w, sorted_codes, k, l)
     if not use_pallas:
         lo, hi = bucket_probe_multi_ref(q, w, sorted_codes, masks, k=k, l=l)
     else:
-        b, d = q.shape
-        _, n = sorted_codes.shape
-        bb, bl, bn = _blocks(b, l, n)
-        b_pad, l_pad, n_pad = (_round_up(b, bb), _round_up(l, bl),
-                               _round_up(n, bn))
-        lo, hi = bucket_probe_multi_pallas(
-            jnp.pad(q, ((0, b_pad - b), (0, 0))),
-            jnp.pad(w, ((0, 0), (0, (l_pad - l) * k))),
-            _pad_sc(sorted_codes, l_pad, n_pad),
-            masks=tuple(masks), k=k, l=l_pad, n_actual=n,
-            block_b=bb, block_l=bl, block_n=bn, interpret=interpret,
-        )
-        # kernel layout: column (t//BL)*J*BL + j*BL + (t%BL); untangle
-        # to (B, J, L) and slice the padding off.
-        def unblock(a):
-            a = a.reshape(b_pad, l_pad // bl, j, bl)
-            return a.transpose(0, 2, 1, 3).reshape(b_pad, j, l_pad)[:b, :, :l]
-        lo, hi = unblock(lo), unblock(hi)
+        lo, hi = _fused(q, w, sorted_codes, masks, k, l, interpret)
     return (lo[0], hi[0]) if squeeze else (lo, hi)
 
 
@@ -162,15 +157,13 @@ def bucket_probe_codes(
         lo, hi = bucket_probe_codes_ref(qcodes, sorted_codes)
     else:
         b, l = qcodes.shape
-        _, n = sorted_codes.shape
-        bb, bl, bn = _blocks(b, l, n)
-        b_pad, l_pad, n_pad = (_round_up(b, bb), _round_up(l, bl),
-                               _round_up(n, bn))
+        n = sorted_codes.shape[1]
+        (bb, bl, bn), (b_pad, l_pad, n_pad) = _blocks(b, l, n)
+        qc = jnp.pad(_bias(qcodes), ((0, b_pad - b), (0, l_pad - l)))
         lo, hi = bucket_probe_codes_pallas(
-            jnp.pad(_bias(qcodes), ((0, b_pad - b), (0, l_pad - l))),
+            qc.reshape(b_pad, l_pad // bl, bl).transpose(1, 0, 2),
             _pad_sc(sorted_codes, l_pad, n_pad),
-            n_actual=n, block_b=bb, block_l=bl, block_n=bn,
-            interpret=interpret,
+            n_actual=n, block_b=bb, block_n=bn, interpret=interpret,
         )
-        lo, hi = lo[:b, :l], hi[:b, :l]
+        lo, hi = _unblock(lo, b, l)[:, 0], _unblock(hi, b, l)[:, 0]
     return (lo[0], hi[0]) if squeeze else (lo, hi)

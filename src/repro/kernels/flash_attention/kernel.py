@@ -144,7 +144,7 @@ def flash_attention_pallas(
 # flash decode: one query token against a long KV cache
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr, acc_scr,
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
                    *, scale: float, bk: int):
     ki = pl.program_id(1)
     nk = pl.num_programs(1)
@@ -158,7 +158,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr, acc_scr,
     q = q_ref[0]                             # (G, D) — all grouped heads
     k = k_ref[0]                             # (bk, D)
     v = v_ref[0]
-    kv_len = len_ref[0]                      # valid cache length
+    kv_len = len_ref[pl.program_id(0)]       # valid cache length (SMEM)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale                                # (G, bk)
@@ -203,22 +203,27 @@ def flash_decode_pallas(
     vf = v_cache.reshape(bh, s, d)
     lens = jnp.repeat(kv_len.astype(jnp.int32), hkv)  # (bh,)
 
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, bk=bk),
+    # the per-row lengths are scalar-prefetch operands (SMEM): a (1,)
+    # block of a vector is not tile-aligned for Mosaic.
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(bh, s // bk),
         in_specs=[
-            pl.BlockSpec((1, g, d), lambda h, j: (h, 0, 0)),
-            pl.BlockSpec((1, bk, d), lambda h, j: (h, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda h, j: (h, j, 0)),
-            pl.BlockSpec((1,), lambda h, j: (h,)),
+            pl.BlockSpec((1, g, d), lambda h, j, lens: (h, 0, 0)),
+            pl.BlockSpec((1, bk, d), lambda h, j, lens: (h, j, 0)),
+            pl.BlockSpec((1, bk, d), lambda h, j, lens: (h, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, g, d), lambda h, j: (h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, g, d), q.dtype),
+        out_specs=pl.BlockSpec((1, g, d), lambda h, j, lens: (h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, bk=bk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bh, g, d), q.dtype),
         interpret=interpret,
-    )(qf, kf, vf, lens)
+    )(lens, qf, kf, vf)
     return out.reshape(b, hkv, g, d)

@@ -1,8 +1,9 @@
 """Jit'd public wrappers: dispatch between the Pallas kernel and the oracle.
 
-The model code calls these; on the TPU target ``use_pallas=True`` is the
-default through configs, while CPU smoke tests run the oracle (XLA:CPU)
-and the kernel tests run interpret mode.
+The model code calls these with ``use_pallas`` from
+``ModelConfig.attn_impl == "pallas"`` (no shipped config sets it, so
+training uses the chunked XLA attention); CPU smoke tests run the oracle
+(XLA:CPU) and the kernel tests run interpret mode.
 """
 
 from __future__ import annotations

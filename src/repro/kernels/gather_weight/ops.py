@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import round_up as _round_up
-from .kernel import gather_weight_pallas
+from .kernel import ROWS_PER_TILE, gather_weight_pallas
 from .ref import gather_weight_ref
 
 _LANE = 128
@@ -42,13 +42,14 @@ def gather_weight(
         return gather_weight_ref(store, idx, probs, p_floor=p_floor)
     n, s = store.shape
     # hot-path note: callers on the kernel path should hand in a store
-    # whose row width is already a lane multiple (the LGD pipeline pads
-    # its device store ONCE at build) — then this pad is zero-width and
-    # compiles away; an unpadded store still works but costs an O(N*S)
-    # copy per call.
+    # whose row width is a lane multiple and whose row count is a
+    # sublane-tile multiple (the LGD pipeline pads its device store ONCE
+    # at build) — then this pad is zero-width and compiles away; an
+    # unpadded store still works but costs an O(N*S) copy per call.
     s_pad = _round_up(s, _LANE)
+    n_pad = _round_up(n, ROWS_PER_TILE)
     rows, w = gather_weight_pallas(
-        jnp.pad(store, ((0, 0), (0, s_pad - s))),
+        jnp.pad(store, ((0, n_pad - n), (0, s_pad - s))),
         idx, probs[:, None],
-        p_floor=p_floor, interpret=interpret)
+        n_points=n, p_floor=p_floor, interpret=interpret)
     return rows[:, :s], w[:, 0]

@@ -6,100 +6,130 @@ The hot op of LGD's preprocessing/refresh path: hash every training point
     codes[n, t] = sum_k (x[n] @ w[:, t*K + k] >= 0) << k        (uint32)
 
 HARDWARE ADAPTATION (vs. the paper's CPU sparse projections): on TPU the
-MXU makes a *dense* (BN, d) @ (d, BL*K) tile matmul essentially free
+MXU makes a *dense* (BN, d) @ (d, TW) tile matmul essentially free
 compared to the HBM traffic of streaming X, so instead of sparse
 multiplications we fuse the full projection, the sign, and the bit-pack
 into one VMEM-resident pass — one read of X, one tiny write of codes
 (32x smaller than the projection output it replaces).  The pack is a
-dot-product with the power-of-two vector so it also runs on the MXU/VPU
-rather than looping over bits.
+second MXU dot of the 0/1 bits with a constant power-of-two matrix.
 
-Block layout:
-  grid  = (N / BN, L / BL)
-  x     : (BN, d)       — full feature dim resident in VMEM (d <= few k)
-  w     : (d, BL*K)     — projections for BL tables
-  codes : (BN, BL)      — uint32 output tile
-VMEM per step ~ BN*d + d*BL*K + BN*BL*K floats; defaults keep this
-< 4 MiB for d up to 4096 with BN=256, BL=8, K<=32.
+Tile layout (Mosaic blocks must be (8, 128)-aligned or whole dims):
+  * tables are processed BL at a time; table tile j's BL*K projection
+    columns are zero-padded to TW = round_up(BL*K, 128) lanes
+    (``tile_projections``: (L/BL, d, TW)), so the projection block is
+    lane-dense for any K;
+  * the pack matrix (``pack_matrices``: (2, TW, BL)) maps bit k of table
+    t to 2^(k mod 16) in half k // 16 — both halves are sums of distinct
+    powers of two below 2^16, exact in f32 whatever the MXU's input
+    precision, and the two halves assemble any K <= 32 exactly;
+    zero-padded columns have all-zero pack rows and never count;
+  * codes come out as (L/BL, N, BL) int32 — the block's minor dim is the
+    whole BL axis — and the wrapper transposes them to (N, L) uint32.
 
-PERFORMANCE.  This is the hot op of ``build_index``/``refresh_index``
-(`repro.core.tables`): one fused pass replaces three XLA ops (matmul,
-compare, reduce-pack) and the (N, L*K) f32 projection intermediate —
-the dominant HBM round-trip at refresh time — never leaves VMEM.
+  grid  = (L/BL, N/BN)   — tables outer, so a projection tile stays
+                           resident while the points stream past it
+  x     : (BN, d)        — BN sized so the block stays ~2 MiB
+  w     : (d, TW)        — one table tile's projections
+  codes : (BN, BL)       — int32 bit patterns of the uint32 codes
+
+The projection runs at ``Precision.HIGHEST`` (as does the XLA reference),
+so a code bit differs between the two only when a projection lies within
+f32 summation-order rounding of zero.
 
 FALLBACK CONTRACT.  ``ops.simhash_codes(use_pallas=False)`` lowers to
-``ref.simhash_codes_ref`` and is bit-identical to the kernel (both are
-f32 matmul + sign + exact pack); ``use_pallas=True, interpret=True``
-runs this kernel under the Pallas interpreter and is the parity surface
-CI pins on CPU.  Callers auto-dispatch via
-``repro.kernels.default_use_pallas()`` — TPU gets the kernel, every
-other backend gets the identical XLA reference, so results never depend
-on the platform.
+``ref.simhash_codes_ref``; ``use_pallas=True, interpret=True`` runs this
+kernel under the Pallas interpreter and is the parity surface CI pins on
+CPU.  Callers auto-dispatch via ``repro.kernels.default_use_pallas()``.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
+from .. import round_up
 
-DEFAULT_BN = 256
-DEFAULT_BL = 8
+DEFAULT_BL = 8                 # tables per tile
+MAX_BN = 256                   # point rows per block
+_X_BLOCK_BYTES = 2 << 20       # VMEM budget for one (BN, d) f32 block
+_LANE = 128
 
 
-def _simhash_kernel(x_ref, w_ref, out_ref, *, k: int, bl: int):
-    x = x_ref[...]                      # (BN, d)
-    w = w_ref[...]                      # (d, BL*K)
-    proj = jnp.dot(x, w, preferred_element_type=jnp.float32)  # (BN, BL*K) MXU
-    bn = proj.shape[0]
-    if k <= 24:
-        # MXU-friendly pack: dot with the power-of-two vector (exact for
-        # K <= 24 since float32 holds integers up to 2^24 exactly).
-        bits = (proj >= 0.0).astype(jnp.float32).reshape(bn, bl, k)
-        weights = (2.0 ** jnp.arange(k, dtype=jnp.float32))   # (K,)
-        packed = jax.lax.dot_general(
-            bits, weights[:, None],
-            dimension_numbers=(((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (BN, BL, 1)
-        out_ref[...] = packed[..., 0].astype(jnp.uint32)
-    else:
-        # exact integer pack on the VPU for 24 < K <= 32
-        bits = (proj >= 0.0).reshape(bn, bl, k).astype(jnp.uint32)
-        weights = jnp.uint32(1) << jnp.arange(k, dtype=jnp.uint32)
-        out_ref[...] = jnp.sum(bits * weights, axis=-1, dtype=jnp.uint32)
+def block_rows(d: int, cap: int = MAX_BN) -> int:
+    """Rows per (rows, d) f32 block: a multiple of 8 within the budget."""
+    return max(8, min(cap, _X_BLOCK_BYTES // (4 * d)) // 8 * 8)
+
+
+def tile_width(k: int, bl: int) -> int:
+    return round_up(bl * k, _LANE)
+
+
+def tile_projections(w: jax.Array, *, k: int, bl: int) -> jax.Array:
+    """(d, L*K) projections -> (L/BL, d, TW) lane-padded table tiles."""
+    d, lk = w.shape
+    n_lt = lk // (bl * k)
+    w3 = w.astype(jnp.float32).reshape(d, n_lt, bl * k).transpose(1, 0, 2)
+    return jnp.pad(w3, ((0, 0), (0, 0), (0, tile_width(k, bl) - bl * k)))
+
+
+def pack_matrices(k: int, bl: int) -> jax.Array:
+    """(2, TW, BL) f32: [h, t*K + b, t] = 2^(b - 16h) for bit b in half h."""
+    m = np.zeros((2, tile_width(k, bl), bl), np.float32)
+    for t in range(bl):
+        for b in range(k):
+            m[b // 16, t * k + b, t] = float(2 ** (b % 16))
+    return jnp.asarray(m)
+
+
+def hash_tile(x: jax.Array, w: jax.Array, pack_ref) -> jax.Array:
+    """In-kernel hash of a (R, d) block by one (d, TW) table tile.
+
+    Returns (R, BL) int32: the bit patterns of the packed uint32 codes.
+    """
+    proj = jax.lax.dot_general(
+        x, w, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)                # (R, TW)
+    bits = jnp.where(proj >= 0.0, 1.0, 0.0)
+    lo = jnp.dot(bits, pack_ref[0], preferred_element_type=jnp.float32)
+    hi = jnp.dot(bits, pack_ref[1], preferred_element_type=jnp.float32)
+    return lo.astype(jnp.int32) | (hi.astype(jnp.int32) << 16)
+
+
+def _simhash_kernel(x_ref, w_ref, pack_ref, out_ref):
+    out_ref[...] = hash_tile(x_ref[...], w_ref[...], pack_ref)
 
 
 def simhash_codes_pallas(
     x: jax.Array,
-    w: jax.Array,
+    w_tiles: jax.Array,
+    pack: jax.Array,
     *,
-    k: int,
-    l: int,
-    block_n: int = DEFAULT_BN,
-    block_l: int = DEFAULT_BL,
+    block_n: int,
     interpret: bool = False,
 ) -> jax.Array:
-    """Packed SimHash codes for a batch of points.
+    """Packed SimHash code bits for a batch of points.
 
-    x: (N, d) float; w: (d, L*K) float.  Returns (N, L) uint32.
-    N must be a multiple of block_n and L of block_l (ops.py pads).
+    x: (N, d) f32 with N % block_n == 0; w_tiles: (L/BL, d, TW) from
+    ``tile_projections``; pack: (2, TW, BL) from ``pack_matrices``.
+    Returns (L/BL, N, BL) int32 (``ops.simhash_codes`` untangles it).
     """
     n, d = x.shape
-    assert w.shape == (d, l * k), (w.shape, d, l, k)
-    assert n % block_n == 0 and l % block_l == 0, (n, l, block_n, block_l)
-    grid = (n // block_n, l // block_l)
+    n_lt, dw, tw = w_tiles.shape
+    bl = pack.shape[2]
+    assert dw == d and pack.shape[1] == tw and n % block_n == 0, (
+        x.shape, w_tiles.shape, pack.shape, block_n)
     return pl.pallas_call(
-        functools.partial(_simhash_kernel, k=k, bl=block_l),
-        grid=grid,
+        _simhash_kernel,
+        grid=(n_lt, n // block_n),
         in_specs=[
-            pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((d, block_l * k), lambda i, j: (0, j)),
+            pl.BlockSpec((block_n, d), lambda j, i: (i, 0)),
+            pl.BlockSpec((None, d, tw), lambda j, i: (j, 0, 0)),
+            pl.BlockSpec((2, tw, bl), lambda j, i: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((block_n, block_l), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n, l), jnp.uint32),
+        out_specs=pl.BlockSpec((None, block_n, bl), lambda j, i: (j, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_lt, n, bl), jnp.int32),
         interpret=interpret,
-    )(x.astype(jnp.float32), w.astype(jnp.float32))
+    )(x, w_tiles, pack)

@@ -8,7 +8,13 @@ import jax
 import jax.numpy as jnp
 
 from .. import round_up as _round_up
-from .kernel import DEFAULT_BL, DEFAULT_BN, simhash_codes_pallas
+from .kernel import (
+    DEFAULT_BL,
+    block_rows,
+    pack_matrices,
+    simhash_codes_pallas,
+    tile_projections,
+)
 from .ref import simhash_codes_ref
 
 
@@ -30,13 +36,14 @@ def simhash_codes(
     if not use_pallas:
         return simhash_codes_ref(x, w, k=k, l=l)
     n, d = x.shape
-    bn = min(DEFAULT_BN, _round_up(n, 8))
+    bn = min(block_rows(d), _round_up(n, 8))
     bl = min(DEFAULT_BL, l)
     n_pad = _round_up(n, bn)
     l_pad = _round_up(l, bl)
-    xp = jnp.pad(x, ((0, n_pad - n), (0, 0)))
+    xp = jnp.pad(x.astype(jnp.float32), ((0, n_pad - n), (0, 0)))
     wp = jnp.pad(w, ((0, 0), (0, (l_pad - l) * k)))
     codes = simhash_codes_pallas(
-        xp, wp, k=k, l=l_pad, block_n=bn, block_l=bl, interpret=interpret
-    )
-    return codes[:n, :l]
+        xp, tile_projections(wp, k=k, bl=bl), pack_matrices(k, bl),
+        block_n=bn, interpret=interpret)                 # (L/BL, N, BL)
+    codes = codes.transpose(1, 0, 2).reshape(n_pad, l_pad)[:n, :l]
+    return jax.lax.bitcast_convert_type(codes, jnp.uint32)

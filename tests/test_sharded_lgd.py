@@ -453,7 +453,7 @@ class TestDPAllReduceComposition:
         script = textwrap.dedent("""
             import jax, numpy as np, jax.numpy as jnp
             from jax.sharding import PartitionSpec as P
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from repro.data import LSHPipelineConfig, ShardedLSHPipeline
             from repro.dist.sharding import batch_sharding
 
@@ -472,7 +472,10 @@ class TestDPAllReduceComposition:
                                   normalize_weights=False),
                 n_shards=4, params=PARAMS, mesh=mesh)
             b = pipe.next_batch()
-            v = jnp.mean(EMBED[b["tokens"]], axis=(1, 2)) + 2.0
+            # per-example values on the host: indexing a replicated
+            # table with a data-sharded index array needs a mesh context
+            v = jnp.asarray(np.asarray(EMBED)[np.asarray(b["tokens"])]
+                            .mean(axis=(1, 2)) + 2.0)
             host = float(jnp.mean(b["loss_weights"] * v))
 
             @jax.jit
@@ -487,14 +490,61 @@ class TestDPAllReduceComposition:
             assert abs(dist - host) < 1e-5 * max(1.0, abs(host)), (dist, host)
             print("OK", dist, host)
         """)
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                            " --xla_force_host_platform_device_count=4")
-        env["JAX_PLATFORMS"] = "cpu"
-        env["PYTHONPATH"] = os.pathsep.join(
-            [os.path.join(os.path.dirname(__file__), "..", "src")]
-            + env.get("PYTHONPATH", "").split(os.pathsep))
-        r = subprocess.run([sys.executable, "-c", script], env=env,
-                           capture_output=True, text=True, timeout=300)
-        assert r.returncode == 0, r.stdout + r.stderr
-        assert "OK" in r.stdout
+        _run_on_4_devices(script)
+
+    def test_each_shard_lives_on_one_device(self):
+        """With mesh-sharded params, every shard's store, features, index
+        and query sit on ONE device of its DP group (per-shard programs,
+        Pallas kernels among them, must not span the mesh), the four
+        shards cover the four devices, and refreshes keep it so."""
+        script = textwrap.dedent("""
+            import jax, numpy as np, jax.numpy as jnp
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            from repro.data import LSHPipelineConfig, ShardedLSHPipeline
+            from repro.launch.mesh import make_host_mesh
+
+            mesh = make_host_mesh()
+            VOCAB, DIM = 50, 16
+            PARAMS = jax.device_put(
+                {"embed": jax.random.normal(jax.random.PRNGKey(1),
+                                            (VOCAB, DIM)),
+                 "q": jnp.ones((DIM,))},
+                NamedSharding(mesh, P()))
+            tokens = np.asarray(jax.random.randint(
+                jax.random.PRNGKey(2), (96, 9), 0, VOCAB), np.int32)
+            ffn = jax.jit(lambda p, c: jnp.mean(p["embed"][c], axis=1))
+            pipe = ShardedLSHPipeline(
+                jax.random.PRNGKey(7), tokens, ffn, lambda p: p["q"],
+                LSHPipelineConfig(k=4, l=8, minibatch=16, refresh_every=1),
+                n_shards=4, params=PARAMS, mesh=mesh)
+            for _ in range(3):
+                b = pipe.next_batch()
+            homes = []
+            for p in pipe.shards:
+                sets = {frozenset(x.sharding.device_set) for x in (
+                    p.store, p.features, p.index.sorted_codes,
+                    p.index.order, p._query())}
+                assert len(sets) == 1 and len(next(iter(sets))) == 1, sets
+                homes.append(next(iter(sets)))
+            assert set().union(*homes) == set(jax.devices()), homes
+            assert b["tokens"].sharding.device_set == set(jax.devices())
+            assert pipe.health_summary()["refreshes"] >= 1
+            print("OK")
+        """)
+        _run_on_4_devices(script)
+
+
+def _run_on_4_devices(script: str):
+    """Run ``script`` in a subprocess on a forced 4-device CPU host (the
+    device count must be fixed before jax initialises)."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
+                        " --xla_force_host_platform_device_count=4")
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src")]
+        + env.get("PYTHONPATH", "").split(os.pathsep))
+    r = subprocess.run([sys.executable, "-c", script], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "OK" in r.stdout

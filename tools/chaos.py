@@ -168,6 +168,11 @@ def drill_host_loss(steps: int, verbose: bool = False) -> dict:
     from repro.dist.multihost_worker import replay_post_reform
     from repro.testing import ProcKill
 
+    if jax.default_backend() != "cpu":
+        # the workers run on the CPU (one chip admits one process), and
+        # the digest replay below must run on the same backend
+        raise SystemExit("the host-loss drill runs CPU processes only: "
+                         "run it with JAX_PLATFORMS=cpu")
     steps = max(steps, 25)               # room for ckpt + sync + kill
     with tempfile.TemporaryDirectory() as d:
         coord = f"127.0.0.1:{_free_port()}"
@@ -187,7 +192,7 @@ def drill_host_loss(steps: int, verbose: bool = False) -> dict:
         ) for r in (0, 1)]
         rcs = [p.wait(timeout=600) for p in procs]
 
-        report = {"fault": "host-loss", "steps": steps,
+        report = {"fault": "host-loss", "backend": "cpu", "steps": steps,
                   "exit_codes": rcs, "survived": False}
         res_path = os.path.join(d, "r0.json")
         if rcs[0] != 0 or rcs[1] != ProcKill.EXIT_CODE or \
@@ -230,7 +235,7 @@ def main(argv=None) -> int:
     if args.drill == "host-loss":
         r = drill_host_loss(args.steps, verbose=args.verbose)
         verdict = "SURVIVED" if r["survived"] else "DIED"
-        print(f"[{verdict}] host-loss exit_codes={r['exit_codes']} "
+        print(f"[{verdict}] host-loss (cpu) exit_codes={r['exit_codes']} "
               f"incident={r.get('incident')} "
               f"reform_shards={r.get('reform_shards')} "
               f"digest_match={r.get('digest_match')} "
