@@ -179,6 +179,7 @@ from repro.dist.sharding import (
     shard_store_device,
 )
 from repro.kernels import default_use_pallas
+from repro.spans import Spans, Totals, merged
 from .health import (
     HEALTHY,
     STALE_INDEX,
@@ -434,6 +435,10 @@ class LSHSampledPipeline:
         self._build_key = jax.random.fold_in(key, _SALT_BUILD)
         self._step = 0
         self._refresh_count = 0
+        # lgd/... spans of the draw, index/... of the refresh (the
+        # refresh worker writes only index/refresh, index/embed and
+        # index/rehash)
+        self.spans = Spans()
         self._refresh_thread: Optional[threading.Thread] = None
         self._refresh_box: Optional[dict] = None
         # snapshot of the async refresh's inputs, kept until the swap
@@ -545,15 +550,16 @@ class LSHSampledPipeline:
     # -- features -----------------------------------------------------------
 
     def _embed(self, chunk: jax.Array, params: Any) -> jax.Array:
-        if not self._params_aware:
-            return self.feature_fn(chunk)
-        leaves = jax.tree.leaves(params)
-        sh = getattr(leaves[0], "sharding", None) if leaves else None
-        if isinstance(sh, NamedSharding):
-            # params span a mesh, the store sits on one device of it:
-            # embed on the mesh, keep the features beside the store
-            chunk = jax.device_put(chunk, NamedSharding(sh.mesh, P()))
-        return self._on_store(self.feature_fn(params, chunk))
+        with self.spans("index/embed"):
+            if not self._params_aware:
+                return self.feature_fn(chunk)
+            leaves = jax.tree.leaves(params)
+            sh = getattr(leaves[0], "sharding", None) if leaves else None
+            if isinstance(sh, NamedSharding):
+                # params span a mesh, the store sits on one device of it:
+                # embed on the mesh, keep the features beside the store
+                chunk = jax.device_put(chunk, NamedSharding(sh.mesh, P()))
+            return self._on_store(self.feature_fn(params, chunk))
 
     def _on_store(self, x: jax.Array) -> jax.Array:
         """``x`` on the store's device (params-derived values: features,
@@ -669,12 +675,13 @@ class LSHSampledPipeline:
         ids = jnp.flatnonzero(dirty, size=size,
                               fill_value=jnp.argmax(dirty))
         feats_d = self._embed_rows(ids, params, scale=scale, store=store)
-        codes_d = hash_points(feats_d, index.projections, self.lsh,
-                              use_pallas=self.cfg.use_pallas,
-                              interpret=self.cfg.interpret)
-        return (features.at[ids].set(feats_d),
-                mutate_index(index,
-                             IndexMutation("delta", ids=ids, codes=codes_d)))
+        with self.spans("index/rehash"):
+            codes_d = hash_points(feats_d, index.projections, self.lsh,
+                                  use_pallas=self.cfg.use_pallas,
+                                  interpret=self.cfg.interpret)
+            new_index = mutate_index(
+                index, IndexMutation("delta", ids=ids, codes=codes_d))
+        return features.at[ids].set(feats_d), new_index
 
     # -- refresh resilience --------------------------------------------------
 
@@ -717,12 +724,13 @@ class LSHSampledPipeline:
         if full:
             feats, new_scale = self._compute_features_scaled(
                 params, store=store, live=live)
-            new_index = mutate_index(
-                index,
-                IndexMutation("refresh", key=kr, x_aug=feats,
-                              live_mask=live, warm_start=True),
-                self.lsh,
-                use_pallas=self.cfg.use_pallas, interpret=self.cfg.interpret)
+            with self.spans("index/rehash"):
+                new_index = mutate_index(
+                    index,
+                    IndexMutation("refresh", key=kr, x_aug=feats,
+                                  live_mask=live, warm_start=True),
+                    self.lsh, use_pallas=self.cfg.use_pallas,
+                    interpret=self.cfg.interpret)
             return feats, new_index, new_scale
         feats, new_index = self._delta_refresh_values(
             kr, params, dirty, features, index, scale=scale, store=store,
@@ -809,10 +817,12 @@ class LSHSampledPipeline:
         full = (self.cfg.refresh_mode != "delta") if full is None else full
         kr = jax.random.fold_in(self._refresh_stream, self._refresh_count)
         dirty = self._take_dirty()
-        ok = self._retry_refresh(kr, full, dirty, self.params,
-                                 self.features, self.index,
-                                 self._feat_scale, self.store,
-                                 self._live_dev)
+        with self.spans("index/refresh", refresh=self._refresh_count,
+                        step=self._step):
+            ok = self._retry_refresh(kr, full, dirty, self.params,
+                                     self.features, self.index,
+                                     self._feat_scale, self.store,
+                                     self._live_dev)
         self._refresh_count += 1
         return ok
 
@@ -833,6 +843,7 @@ class LSHSampledPipeline:
         old_capacity = self.capacity
         self._touched = set()
         box: dict = {}
+        count, step = self._refresh_count, self._step
 
         def work():
             # attribute-write-free: features/index/scale are committed
@@ -840,9 +851,10 @@ class LSHSampledPipeline:
             # refresh cannot leave self._feat_scale out of sync with
             # the live (features, index) pair.
             try:
-                box["result"] = self._attempt_refresh(
-                    kr, full, dirty, params, old_features, old_index,
-                    old_scale, old_store, old_live, attempt=0)
+                with self.spans("index/refresh", refresh=count, step=step):
+                    box["result"] = self._attempt_refresh(
+                        kr, full, dirty, params, old_features, old_index,
+                        old_scale, old_store, old_live, attempt=0)
             except BaseException as e:   # handled at the swap boundary
                 box["error"] = e
 
@@ -1044,7 +1056,9 @@ class LSHSampledPipeline:
             if s + lead >= re and (s + lead) % re == 0:
                 self._launch_refresh()
             if s >= re and s % re == 0:
-                self._swap_refresh()
+                with self.spans("index/refresh_join",
+                                refresh=self._refresh_count, step=s):
+                    self._swap_refresh()
         elif s >= re and s % re == 0:
             self.refresh()
 
@@ -1489,23 +1503,38 @@ class LSHSampledPipeline:
             use_pallas=self.cfg.use_pallas, interpret=self.cfg.interpret,
             row_width=self.row_width, n_live=self._n_live_dev)
 
+    def span_totals(self) -> Totals:
+        """``{name: (seconds, calls)}`` of this pipeline's spans."""
+        return self.spans.totals()
+
     def next_batch(self, query: Optional[jax.Array] = None
                    ) -> Dict[str, jax.Array]:
         """Draw one batch — a single jitted on-device program; ``query``
         (already normalised) lets a sharded owner compute the shared
         global query once for all shards."""
+        with self.spans("lgd/draw", step=self._step):
+            return self._draw(query)
+
+    def _draw(self, query: Optional[jax.Array]) -> Dict[str, jax.Array]:
+        """``next_batch`` inside its ``lgd/draw`` span, or inside the
+        sharded owner's."""
         if self.streaming and self._n_live == 0:
             raise RuntimeError(
                 "cannot draw a batch from an empty streaming window "
                 "(append rows first)")
+        step = self._step
         sub = self._tick()
         if self.health.state == UNIFORM_FALLBACK:
-            return self._uniform_batch(sub, self.cfg.minibatch)
-        q = self._query() if query is None else self._on_store(query)
-        args, kw = self.draw_inputs(sub, q)
-        gb = sample_gather(*args, **kw)
-        self._mark_dirty(gb.indices)
-        self._accum_stats(gb)
+            with self.spans("lgd/sample", step=step):
+                return self._uniform_batch(sub, self.cfg.minibatch)
+        if query is None:
+            with self.spans("lgd/query", step=step):
+                query = self._query()
+        with self.spans("lgd/sample", step=step):
+            args, kw = self.draw_inputs(sub, self._on_store(query))
+            gb = sample_gather(*args, **kw)
+            self._mark_dirty(gb.indices)
+            self._accum_stats(gb)
         return {
             "tokens": gb.tokens,
             "targets": gb.targets,
@@ -1695,6 +1724,7 @@ class ShardedLSHPipeline:
             normalize_weights=False, window=shard_window)
         self.shards: List[LSHSampledPipeline] = [
             self._make_shard(s, params) for s in self.owned]
+        self.spans = Spans()       # lgd/draw, lgd/query and lgd/compose
 
     def _make_shard(self, s: int, params: Any) -> "LSHSampledPipeline":
         """Build shard ``s``'s pipeline — keyed by ``fold_in(key, s)``
@@ -1910,6 +1940,12 @@ class ShardedLSHPipeline:
                 np.mean([s["last_fallback_rate"] for s in per])),
         }
 
+    def span_totals(self) -> Totals:
+        """``{name: (seconds, calls)}`` of the owner's spans and its
+        shards', summed."""
+        return merged(self.spans.totals(),
+                      *(p.span_totals() for p in self.shards))
+
     def _compose(self, parts: list) -> jax.Array:
         # the zero-copy mesh composition lays out the FULL global batch;
         # a partial owner's batch is its local slice — plain concat.
@@ -1920,12 +1956,19 @@ class ShardedLSHPipeline:
         return jnp.concatenate(parts)
 
     def next_batch(self) -> Dict[str, jax.Array]:
-        # the global query is shard-independent: compute + normalise it
-        # once and share it across all owned per-shard sample calls
-        # (bitwise the same value on every process — query_fn sees only
-        # the replicated params, never the shard).
-        q = self.shards[0]._query()
-        subs = [p.next_batch(query=q) for p in self.shards]
+        step = self.shards[0]._step
+        with self.spans("lgd/draw", step=step):
+            # the global query is shard-independent: compute + normalise
+            # it once and share it across all owned per-shard sample calls
+            # (bitwise the same value on every process — query_fn sees
+            # only the replicated params, never the shard).
+            with self.spans("lgd/query", step=step):
+                q = self.shards[0]._query()
+            subs = [p._draw(q) for p in self.shards]
+            with self.spans("lgd/compose", step=step):
+                return self._compose_batch(subs)
+
+    def _compose_batch(self, subs: list) -> Dict[str, jax.Array]:
         m_s = self.cfg.minibatch // self.n_shards
         batch = {
             k: self._compose([b[k] for b in subs])
@@ -1961,9 +2004,10 @@ def mean_pool_feature_fn(cfg):
     ``feature_fn`` with ``params=`` so the trainer keeps it fresh."""
     from repro.models.lm import pooled_features
 
-    def fn(params, tokens: jax.Array) -> jax.Array:
+    def refresh_embed(params, tokens: jax.Array) -> jax.Array:
         return pooled_features(params, cfg, {"tokens": tokens})
-    return jax.jit(fn)
+    # compiles as ``jit_refresh_embed``, the name the device trace shows
+    return jax.jit(refresh_embed)
 
 
 def lm_head_query_fn():

@@ -138,6 +138,9 @@ def main(argv=None):
     tr.finalize()
     for m in tr.metrics_history[-5:]:
         print(m)
+    # where the host time went: every span's calls and seconds
+    for name, (sec, calls) in sorted(tr.span_totals().items()):
+        print(f"span {name}: {calls} calls, {sec:.3f} s")
 
 
 if __name__ == "__main__":

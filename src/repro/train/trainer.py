@@ -20,9 +20,18 @@ Fault-tolerance contract:
 Straggler mitigation (documented policy, host-side): per-step wall-time
 is tracked with an EWMA; steps exceeding ``straggler_factor`` x EWMA are
 counted and surfaced in metrics — on a fleet this signal feeds the
-controller that evicts/replaces slow hosts.  Data loading is
-double-buffered (next batch prepared while the step runs) so host-side
-sampling (the LGD hash lookups) overlaps device compute.
+controller that evicts/replaces slow hosts.
+
+Where the host time goes: the next batch is drawn only after the host
+has read the step's ``ok`` flag (``bool(ok)``) and, with a sampler, its
+loss (``float(l)``), so the draw waits for the step instead of
+overlapping it.  Each step opens profiler spans (``repro.spans``):
+``StepTraceAnnotation("train")`` around the iteration, ``train/dispatch``
+around the step's dispatch, ``train/sync`` around each read of its
+results and ``train/draw`` around each batch draw (with the sampler's
+own ``lgd/...`` and ``index/...`` spans inside).  ``span_totals()`` sums
+their seconds and calls without a device sync; ``data_seconds`` is the
+``train/draw`` total.
 
 ADAPTIVE OPTIMIZERS under LGD: the sampler composes with ANY
 ``repro.optim`` optimiser (Adam, AdaGrad, momentum-SGD, ...) because
@@ -55,13 +64,6 @@ LGD sampler hook: pass ``sampler=`` (an ``LSHSampledPipeline`` /
   * forces ``donate=False`` (the sampler's feature/query closures read
     live param buffers) and, on restore, rewinds the sampler with
     ``restore_at(step)`` instead of replaying consumed batches.
-
-Sampler-overhead accounting: the host-blocking time spent drawing every
-batch is accumulated in ``data_seconds`` (total loop wall time in
-``loop_seconds``); ``sampler_overhead`` is their ratio and per-entry
-``metrics_history`` carries ``data_dt`` (the LAST draw's host-blocking
-seconds, per-step like ``dt``) — the number the device-resident data
-path is meant to drive toward zero.
 """
 
 from __future__ import annotations
@@ -72,9 +74,11 @@ from typing import Callable, Dict, Iterator, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation
 
 from repro.models import ModelConfig, loss as lm_loss
 from repro.optim import apply_updates
+from repro.spans import Spans, Totals, merged
 from . import checkpoint as ckpt
 
 
@@ -186,9 +190,7 @@ class Trainer:
         self.skipped_steps = 0      # non-finite steps (no update applied)
         self.rollbacks = 0          # checkpoint rollbacks taken
         self._bad_streak = 0        # consecutive skipped steps
-        self.data_seconds = 0.0     # host-blocking batch-draw time (total)
-        self.loop_seconds = 0.0     # total run() wall time
-        self._last_draw_dt = 0.0    # host-blocking time of the last draw
+        self.spans = Spans()        # train/... host spans (module docstring)
         loss_fn = loss_fn or (lambda p, b: lm_loss(p, cfg, b))
 
         clip = tcfg.grad_clip
@@ -371,17 +373,23 @@ class Trainer:
         return self._sampler
 
     @property
-    def sampler_overhead(self) -> float:
-        """Fraction of loop wall time spent blocked on batch draws."""
-        return self.data_seconds / max(self.loop_seconds, 1e-12)
+    def data_seconds(self) -> float:
+        """Host time spent blocked on batch draws (``train/draw``)."""
+        return self.spans.seconds("train/draw")
 
-    def _draw(self):
-        t0 = time.time()
-        try:
+    def span_totals(self) -> Totals:
+        """``{name: (seconds, calls)}`` of the trainer's spans and its
+        sampler's, summed over the run so far (no device sync)."""
+        own = self.spans.totals()
+        if self._sampler is not None and \
+                hasattr(self._sampler, "span_totals"):
+            return merged(own, self._sampler.span_totals())
+        return own
+
+    def _draw(self, step: int):
+        """The batch that trains step ``step`` (0-based)."""
+        with self.spans("train/draw", step=step):
             return next(self.batches)
-        finally:
-            self._last_draw_dt = time.time() - t0
-            self.data_seconds += self._last_draw_dt
 
     def run(self, n_steps: int) -> Dict[str, list]:
         losses = []
@@ -390,106 +398,111 @@ class Trainer:
             # and a no-op run() must not tick the sampler's key stream.
             return {"losses": losses}
         target = self.step + n_steps
-        t_loop = time.time()
         try:
-            next_batch = self._draw()            # double buffering
+            next_batch = self._draw(self.step)
         except StopIteration:
             # an empty/exhausted iterator on the FIRST draw is a clean
             # no-op run, not a crash (satellite: bare StopIteration).
-            self.loop_seconds += time.time() - t_loop
             return {"losses": losses}
         while self.step < target:
-            t0 = time.time()
-            batch = next_batch
-            self.params, self.opt_state, l, gnorm, ef, ok = self._step_fn(
-                self.params, self.opt_state, batch,
-                getattr(self, "_ef_residual", None))
-            if ef is not None:
-                self._ef_residual = ef
-            ok = bool(ok) if self.tcfg.skip_nonfinite else True
-            if ok:
-                self._bad_streak = 0
-            else:
-                self.skipped_steps += 1
-                self._bad_streak += 1
-            if self._sampler is not None and \
-                    hasattr(self._sampler, "note_loss"):
-                # feed the degradation ladder: a non-finite streak sends
-                # the pipeline to uniform-fallback (weights un-poisoned
-                # by construction).
-                self._sampler.note_loss(ok)
-            if not ok and self.tcfg.rollback_after > 0 and \
-                    self._bad_streak >= self.tcfg.rollback_after and \
-                    self._sampler is not None and self.tcfg.ckpt_dir and \
-                    self.rollbacks < self.tcfg.max_rollbacks:
-                if self._rollback():
-                    # the prefetched batch belongs to the abandoned
-                    # stream position; re-draw at the rolled-back step.
-                    next_batch = self._draw()
-                    continue
-            if self._sampler is not None and \
-                    hasattr(self._sampler, "set_params"):
-                # point the sampler at the post-step params (async jax
-                # values — sampling ops just enqueue behind the step)
-                # BEFORE drawing the next batch, so its query reflects
-                # the live model.
-                self._sampler.set_params(self.params)
-                # the draw's query depends on the step's output params,
-                # and dispatching on a pending input blocks on backends
-                # without cross-dependency async (CPU) — sync the loss
-                # first so data_seconds measures the DRAW, not the
-                # in-flight step it would otherwise absorb.
-                l = float(l)
-            if self.step + 1 < target:
-                # prefetch ONLY if another step will run: batch k must
-                # train step k, never be thrown away at loop exit —
-                # otherwise chunked run() calls desync the data stream
-                # from self.step and restore-at-step resume diverges.
-                try:
-                    next_batch = self._draw()        # overlap device step
-                except StopIteration:
+            with StepTraceAnnotation("train", step_num=self.step):
+                t0 = time.perf_counter()
+                with self.spans("train/dispatch", step=self.step):
+                    self.params, self.opt_state, l, gnorm, ef, ok = \
+                        self._step_fn(self.params, self.opt_state,
+                                      next_batch,
+                                      getattr(self, "_ef_residual", None))
+                if ef is not None:
+                    self._ef_residual = ef
+                if self.tcfg.skip_nonfinite:
+                    with self.spans("train/sync", step=self.step):
+                        ok = bool(ok)
+                else:
+                    ok = True
+                if ok:
+                    self._bad_streak = 0
+                else:
+                    self.skipped_steps += 1
+                    self._bad_streak += 1
+                if self._sampler is not None and \
+                        hasattr(self._sampler, "note_loss"):
+                    # feed the degradation ladder: a non-finite streak sends
+                    # the pipeline to uniform-fallback (weights un-poisoned
+                    # by construction).
+                    self._sampler.note_loss(ok)
+                if not ok and self.tcfg.rollback_after > 0 and \
+                        self._bad_streak >= self.tcfg.rollback_after and \
+                        self._sampler is not None and self.tcfg.ckpt_dir and \
+                        self.rollbacks < self.tcfg.max_rollbacks:
+                    if self._rollback():
+                        # the prefetched batch belongs to the abandoned
+                        # stream position; re-draw at the rolled-back step.
+                        next_batch = self._draw(self.step)
+                        continue
+                if self._sampler is not None and \
+                        hasattr(self._sampler, "set_params"):
+                    # point the sampler at the post-step params (async jax
+                    # values — sampling ops just enqueue behind the step)
+                    # BEFORE drawing the next batch, so its query reflects
+                    # the live model.
+                    self._sampler.set_params(self.params)
+                    # the draw's query depends on the step's output params,
+                    # and dispatching on a pending input blocks on backends
+                    # without cross-dependency async (CPU) — sync the loss
+                    # first so data_seconds measures the DRAW, not the
+                    # in-flight step it would otherwise absorb.
+                    with self.spans("train/sync", step=self.step):
+                        l = float(l)
+                if self.step + 1 < target:
+                    # prefetch ONLY if another step will run: batch k must
+                    # train step k, never be thrown away at loop exit —
+                    # otherwise chunked run() calls desync the data stream
+                    # from self.step and restore-at-step resume diverges.
+                    try:
+                        next_batch = self._draw(self.step + 1)
+                    except StopIteration:
+                        next_batch = None
+                else:
                     next_batch = None
-            else:
-                next_batch = None
-            l = float(l)
-            dt = time.time() - t0
-            self._ewma_dt = dt if self._ewma_dt is None else \
-                0.9 * self._ewma_dt + 0.1 * dt
-            if dt > self.tcfg.straggler_factor * self._ewma_dt:
-                self.straggler_steps += 1
-            self.step += 1
-            losses.append(l)
-            if self.step % self.tcfg.log_every == 0:
-                entry = {
-                    "step": self.step, "loss": l,
-                    "grad_norm": float(gnorm), "dt": dt,
-                    "data_dt": self._last_draw_dt,
-                    "stragglers": self.straggler_steps,
-                    "skipped_steps": self.skipped_steps,
-                    "rollbacks": self.rollbacks,
-                }
-                if self._sampler is not None and \
-                        hasattr(self._sampler, "sampler_stats"):
-                    # device-sync'd read, so only at log cadence
-                    st = self._sampler.sampler_stats()
-                    entry["fallback_rate"] = st["fallback_rate"]
-                    entry["primary_miss_rate"] = st["primary_miss_rate"]
-                if self._sampler is not None and \
-                        hasattr(self._sampler, "check_health"):
-                    # feeds the batch fallback rate into the ladder and
-                    # reports the state (syncs; log cadence only)
-                    entry["health"] = self._sampler.check_health()
-                    hs = self._sampler.health_summary()
-                    entry["health_transitions"] = hs["transitions"]
-                self.metrics_history.append(entry)
-            if self.tcfg.ckpt_dir and \
-                    self.step % self.tcfg.ckpt_every == 0:
-                self.save()
-            if self.tcfg.step_hook is not None:
-                # cluster attachment point — may mutate params or raise
-                # (e.g. HostLossDetected) at this clean step boundary.
-                self.tcfg.step_hook(self)
-            if next_batch is None:
-                break
-        self.loop_seconds += time.time() - t_loop
+                if not isinstance(l, float):
+                    with self.spans("train/sync", step=self.step):
+                        l = float(l)
+                dt = time.perf_counter() - t0
+                self._ewma_dt = dt if self._ewma_dt is None else \
+                    0.9 * self._ewma_dt + 0.1 * dt
+                if dt > self.tcfg.straggler_factor * self._ewma_dt:
+                    self.straggler_steps += 1
+                self.step += 1
+                losses.append(l)
+                if self.step % self.tcfg.log_every == 0:
+                    entry = {
+                        "step": self.step, "loss": l,
+                        "grad_norm": float(gnorm), "dt": dt,
+                        "stragglers": self.straggler_steps,
+                        "skipped_steps": self.skipped_steps,
+                        "rollbacks": self.rollbacks,
+                    }
+                    if self._sampler is not None and \
+                            hasattr(self._sampler, "sampler_stats"):
+                        # device-sync'd read, so only at log cadence
+                        st = self._sampler.sampler_stats()
+                        entry["fallback_rate"] = st["fallback_rate"]
+                        entry["primary_miss_rate"] = st["primary_miss_rate"]
+                    if self._sampler is not None and \
+                            hasattr(self._sampler, "check_health"):
+                        # feeds the batch fallback rate into the ladder and
+                        # reports the state (syncs; log cadence only)
+                        entry["health"] = self._sampler.check_health()
+                        hs = self._sampler.health_summary()
+                        entry["health_transitions"] = hs["transitions"]
+                    self.metrics_history.append(entry)
+                if self.tcfg.ckpt_dir and \
+                        self.step % self.tcfg.ckpt_every == 0:
+                    self.save()
+                if self.tcfg.step_hook is not None:
+                    # cluster attachment point — may mutate params or raise
+                    # (e.g. HostLossDetected) at this clean step boundary.
+                    self.tcfg.step_hook(self)
+                if next_batch is None:
+                    break
         return {"losses": losses}
