@@ -550,9 +550,14 @@ class LSHSampledPipeline:
     # -- features -----------------------------------------------------------
 
     def _embed(self, chunk: jax.Array, params: Any) -> jax.Array:
-        with self.spans("index/embed"):
-            if not self._params_aware:
+        if not self._params_aware:
+            with self.spans("index/embed"):
                 return self.feature_fn(chunk)
+        from repro.models.lm import embed_attention
+        # the attention path ``pooled_features`` takes for these params
+        # and rows, so a trace shows which embed calls ran the kernel
+        attn = embed_attention(params, chunk.shape[1])
+        with self.spans("index/embed", attn=attn):
             leaves = jax.tree.leaves(params)
             sh = getattr(leaves[0], "sharding", None) if leaves else None
             if isinstance(sh, NamedSharding):

@@ -8,31 +8,45 @@ Pallas pipeline overlaps the HBM->VMEM streaming of the next KV block
 with the MXU matmuls of the current one.
 
 Causality is exploited structurally: KV blocks strictly above the
-diagonal contribute nothing and their compute is skipped with pl.when
-(the roofline win: 2x fewer MXU FLOPs at long sequence).
+diagonal contribute nothing, so their compute is skipped with pl.when and
+their K/V index map is clamped to the last block the q-block needs, so a
+skipped grid step fetches nothing new (the roofline win: 2x fewer MXU
+FLOPs and K/V bytes at long sequence).  Only blocks that cross the
+diagonal build the causal mask.
 
 GQA: queries arrive grouped as (B, Hkv, G, S, D) so one KV head's block
 is shared by its G query heads without re-streaming K/V — the layout
 turns grouped attention into a plain batched matmul over the fused
-(G*bq, D) tile.
+(G*bq, D) tile.  Both matmuls feed the MXU operands in the inputs' dtype
+(P is cast to V's dtype) and accumulate in f32; the running max,
+normaliser and accumulator stay f32.
 
-Block sizes default to (bq, bk) = (256, 256): MXU-aligned (multiples of
-128 in the contracted dims come from D >= 128) and small enough that
-q/k/v/acc tiles fit VMEM for D <= 256.
+Block sizes default to ``default_blocks(S)``: (512, 512) where they divide
+S, the largest power-of-two divisor down to 128 otherwise — chosen by a
+sweep of {256, 512}^2 on a v5e at Granite-3-8B's embed shape (PERF.md).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BQ = 256
-DEFAULT_BK = 256
+DEFAULT_BQ = 512
+DEFAULT_BK = 512
 NEG_INF = -1e30
+
+
+def default_blocks(s: int) -> tuple[int, int] | None:
+    """(block_q, block_k) for sequence length ``s``, or None where no
+    lane-aligned block divides it (``s`` not a multiple of 128)."""
+    if s % 128:
+        return None
+    return math.gcd(s, DEFAULT_BQ), math.gcd(s, DEFAULT_BK)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
@@ -47,11 +61,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # causal: skip blocks strictly above the diagonal
-    run = (not causal) or (ki * bk < (qi + 1) * bq)
-
-    @pl.when(run)
-    def _step():
+    def step(masked: bool):
         q = q_ref[0]                         # (G*bq, D) fused group-of-queries
         k = k_ref[0]                         # (bk, D)
         v = v_ref[0]                         # (bk, D)
@@ -59,14 +69,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale                            # (G*bq, bk)
-        if causal:
+        if masked:
             g_bq = q.shape[0]
-            g = g_bq // bq
             q_pos = qi * bq + (
                 jax.lax.broadcasted_iota(jnp.int32, (g_bq, bk), 0) % bq
             )
             k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (g_bq, bk), 1)
-            del g
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         m_prev = m_scr[...]                  # (G*bq, 1)
         l_prev = l_scr[...]
@@ -76,11 +84,21 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         m_scr[...] = m_new
         l_scr[...] = l_new
+
+    if causal:
+        # blocks strictly above the diagonal are skipped; those wholly
+        # below it need no mask
+        run = ki * bk < (qi + 1) * bq
+        below = (ki + 1) * bk <= qi * bq + 1
+        pl.when(run & below)(lambda: step(False))
+        pl.when(run & jnp.logical_not(below))(lambda: step(True))
+    else:
+        step(False)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -96,13 +114,19 @@ def flash_attention_pallas(
     *,
     causal: bool = True,
     scale: float | None = None,
-    block_q: int = DEFAULT_BQ,
-    block_k: int = DEFAULT_BK,
+    block_q: int | None = None,
+    block_k: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Returns (B, Hkv, G, S, D) attention output."""
+    """Returns (B, Hkv, G, S, D) attention output.  Block sizes left at
+    None come from ``default_blocks(S)``."""
     b, hkv, g, s, d = q.shape
     assert k.shape == (b, hkv, s, d) and v.shape == (b, hkv, s, d)
+    if block_q is None or block_k is None:
+        blocks = default_blocks(s)
+        assert blocks is not None, f"no default block divides S={s}"
+        block_q = block_q or blocks[0]
+        block_k = block_k or blocks[1]
     bq = min(block_q, s)
     bk = min(block_k, s)
     assert s % bq == 0 and s % bk == 0, (s, bq, bk)
@@ -116,6 +140,15 @@ def flash_attention_pallas(
     kf = k.reshape(bh, s, d)
     vf = v.reshape(bh, s, d)
 
+    if causal:
+        # past the q-block's last needed KV block, keep the block index
+        # unchanged: the pipeline then fetches nothing for skipped steps
+        def kv_map(h, i, j):
+            return (h, jnp.minimum(j, ((i + 1) * bq - 1) // bk), 0)
+    else:
+        def kv_map(h, i, j):
+            return (h, j, 0)
+
     out = pl.pallas_call(
         functools.partial(
             _flash_kernel, scale=scale, bq=bq, bk=bk, causal=causal
@@ -123,8 +156,8 @@ def flash_attention_pallas(
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, g * bq, d), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda h, i, j: (h, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda h, i, j: (h, j, 0)),
+            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, bk, d), kv_map),
         ],
         out_specs=pl.BlockSpec((1, g * bq, d), lambda h, i, j: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, nq * g * bq, d), q.dtype),

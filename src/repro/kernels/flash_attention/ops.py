@@ -1,9 +1,11 @@
 """Jit'd public wrappers: dispatch between the Pallas kernel and the oracle.
 
 The model code calls these with ``use_pallas`` from
-``ModelConfig.attn_impl == "pallas"`` (no shipped config sets it, so
-training uses the chunked XLA attention); CPU smoke tests run the oracle
-(XLA:CPU) and the kernel tests run interpret mode.
+``ModelConfig.attn_impl == "pallas"``.  The kernel's one user is the LGD
+refresh embed (``repro.models.lm.pooled_features``), which takes it on a
+single TPU device; training keeps the chunked XLA attention, since the
+kernel has no backward.  CPU smoke tests run the oracle (XLA:CPU) and
+the kernel tests run interpret mode.
 """
 
 from __future__ import annotations
@@ -27,10 +29,11 @@ def gqa_attention(
     causal: bool = True,
     use_pallas: bool = False,
     interpret: bool = False,
-    block_q: int = 256,
-    block_k: int = 256,
+    block_q: int | None = None,
+    block_k: int | None = None,
 ) -> jax.Array:
-    """Grouped-query attention; returns (B, S, Hq, D)."""
+    """Grouped-query attention; returns (B, S, Hq, D).  The kernel's block
+    sizes default to ``default_blocks(S)``."""
     b, s, hq, d = q.shape
     hkv = k.shape[2]
     assert hq % hkv == 0, (hq, hkv)

@@ -26,7 +26,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.dist.sharding import logical
+from repro.kernels.flash_attention.kernel import default_blocks
 from . import ssm
 from .config import ModelConfig
 from .layers import (
@@ -242,13 +244,32 @@ def logits(params, cfg: ModelConfig, batch) -> jax.Array:
 # LGD feature-extraction hooks (paper Sec. 3.2: the BERT recipe)
 # ---------------------------------------------------------------------------
 
+def embed_attention(params, seq: int) -> str:
+    """The self-attention path of ``pooled_features`` for ``params`` and
+    rows of ``seq`` tokens: ``"flash"``, the causal Pallas kernel, where
+    the backend is TPU, the params live on one device (Mosaic kernels do
+    not run in a mesh-partitioned program) and the kernel's blocks divide
+    ``seq``; else ``"chunked"``, the XLA scan that training runs.  Reads
+    only avals, so it holds at trace time too."""
+    if (kernels.default_use_pallas() and default_blocks(seq) is not None
+            and all(jax.typeof(x).sharding.mesh.size <= 1
+                    for x in jax.tree.leaves(params))):
+        return "flash"
+    return "chunked"
+
+
 def pooled_features(params, cfg: ModelConfig, batch) -> jax.Array:
     """Per-example feature vector: mean-pooled final hidden state (f32).
 
     The paper hashes each example's pooled last-layer representation into
     the LSH index; this is the model-side half of that contract (the
-    pipeline half is ``repro.data.LSHSampledPipeline``).
+    pipeline half is ``repro.data.LSHSampledPipeline``).  Nothing
+    differentiates it, so its self-attention may take the forward-only
+    flash kernel (``embed_attention``).
     """
+    rows = batch["embeds"] if cfg.frontend == "embed_stub" else batch["tokens"]
+    if embed_attention(params, rows.shape[1]) == "flash":
+        cfg = cfg.with_(attn_impl="pallas")
     h = forward(params, cfg, batch)
     return jnp.mean(h.astype(jnp.float32), axis=1)
 

@@ -73,6 +73,10 @@ class TestFlashAttention:
         (2, 2, 4, 128, 64, 64, 64),     # GQA group 4
         (1, 1, 2, 256, 128, 128, 64),   # uneven q/k blocks
         (1, 2, 1, 64, 32, 64, 32),      # single q block
+        # GQA 4, bq != bk both ways: the K/V index map is clamped past
+        # the diagonal and only diagonal-crossing blocks are masked
+        (2, 2, 4, 512, 64, 128, 256),
+        (2, 2, 4, 512, 64, 256, 128),
     ])
     @pytest.mark.parametrize("causal", [True, False])
     def test_matches_ref(self, b, hkv, g, s, d, bq, bk, causal):
@@ -94,6 +98,26 @@ class TestFlashAttention:
             np.asarray(got, np.float32), np.asarray(want, np.float32),
             rtol=3e-2, atol=3e-2,
         )
+
+    @pytest.mark.parametrize("bq,bk", [(128, 256), (256, 128)])
+    def test_bf16_uneven_blocks(self, bq, bk):
+        """bf16 inputs: P is cast to bf16 for the P.V matmul, with f32
+        statistics and accumulation."""
+        q, k, v = _qkv(KEY, 1, 2, 4, 512, 128, jnp.bfloat16)
+        got = flash_attention_pallas(q, k, v, causal=True, block_q=bq,
+                                     block_k=bk, interpret=True)
+        want = attention_ref(q, k, v, causal=True)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=3e-2, atol=3e-2,
+        )
+
+    def test_default_blocks(self):
+        from repro.kernels.flash_attention.kernel import default_blocks
+        assert default_blocks(4096) == (512, 512)
+        assert default_blocks(1024) == (512, 512)
+        assert default_blocks(384) == (128, 128)
+        assert default_blocks(100) is None
 
     def test_gqa_wrapper_model_layout(self):
         b, s, hq, hkv, d = 2, 128, 8, 2, 64

@@ -266,3 +266,65 @@ class TestChunkedAttention:
         for a, b_ in zip(g1, g2):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                        rtol=1e-4, atol=1e-4)
+
+
+class TestEmbedAttention:
+    """``pooled_features`` (the LGD refresh embed) runs self-attention
+    through the flash kernel where ``embed_attention`` says so: TPU, params
+    on one device, kernel blocks dividing the sequence."""
+
+    @pytest.fixture
+    def tpu_path(self, monkeypatch):
+        """The kernel path on this CPU host: the backend rule reads TPU and
+        the kernel runs in interpret mode."""
+        import repro.kernels
+        from repro.kernels.flash_attention import (
+            flash_attention_pallas, gqa_attention, ops)
+
+        def interpreted(*args, **kw):
+            return flash_attention_pallas(*args, **{**kw, "interpret": True})
+        monkeypatch.setattr(repro.kernels, "default_use_pallas",
+                            lambda: True)
+        monkeypatch.setattr(ops, "flash_attention_pallas", interpreted)
+        gqa_attention.clear_cache()
+        yield
+        gqa_attention.clear_cache()
+
+    def test_cpu_takes_the_chunked_path(self):
+        from repro.models.lm import embed_attention
+        params = init_params(KEY, tiny("embed_cpu"))
+        assert embed_attention(params, 128) == "chunked"
+
+    def test_rule(self, tpu_path):
+        from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec
+        from repro.models.lm import embed_attention
+        cfg = tiny("embed_rule")
+        params = init_params(KEY, cfg)
+        assert embed_attention(params, 128) == "flash"
+        assert embed_attention(params, 4096) == "flash"
+        assert embed_attention(params, 100) == "chunked"   # no block fits
+        meshed = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(
+                AbstractMesh((4,), ("data",)), PartitionSpec())), params)
+        assert embed_attention(meshed, 128) == "chunked"   # four devices
+
+    def test_pooled_features_flash_matches_chunked(self, tpu_path):
+        """At smoke width in bf16, the kernel's features equal the chunked
+        scan's within bf16 rounding, and the loss keeps the scan."""
+        import repro.kernels
+        from repro.configs import granite_3_8b
+        from repro.models import pooled_features
+        cfg = granite_3_8b.SMOKE.with_(dtype="bfloat16")
+        params = init_params(KEY, cfg)
+        batch = make_batch(cfg, b=2, s=128)
+        got = jax.jit(lambda p, b: pooled_features(p, cfg, b))(params, batch)
+        loss_text = jax.jit(lambda p, b: loss(p, cfg, b)).lower(
+            params, batch).as_text()
+        repro.kernels.default_use_pallas = lambda: False
+        want = jax.jit(lambda p, b: pooled_features(p, cfg, b))(params, batch)
+        got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+        gap = np.linalg.norm(got - want, axis=1) / np.linalg.norm(
+            want, axis=1)
+        assert gap.max() < 2e-2, gap
+        assert not np.array_equal(got, want)    # the kernel ran
+        assert "pallas" not in loss_text

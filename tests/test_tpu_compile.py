@@ -128,3 +128,37 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     fn, args = CASES[case](one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), case
+
+
+def _granite_one_chip(dev):
+    from repro.configs import granite_3_8b
+    from repro.models import init_params
+    cfg = granite_3_8b.ONE_CHIP
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev), params)
+    tokens = jax.ShapeDtypeStruct((2, SEQ), jnp.int32, sharding=dev)
+    return cfg, params, tokens
+
+
+@pytest.mark.parametrize("program", ["refresh_embed", "loss_and_grad"])
+def test_granite_attention_path_on_v5e(one_chip, monkeypatch, program):
+    """On one TPU device the refresh embed runs its attention through the
+    flash kernel and still compiles as ``jit_refresh_embed``; the loss
+    and its gradient keep the chunked scan (the kernel has no backward)."""
+    import repro.kernels
+    from repro.data import mean_pool_feature_fn
+    from repro.models import loss
+    monkeypatch.setattr(repro.kernels, "default_use_pallas", lambda: True)
+    cfg, params, tokens = _granite_one_chip(one_chip)
+    if program == "refresh_embed":
+        lowered = mean_pool_feature_fn(cfg).lower(params, tokens)
+    else:
+        lowered = jax.jit(lambda p, b: jax.value_and_grad(loss)(p, cfg, b)
+                          ).lower(params, {"tokens": tokens, "targets": tokens})
+    text = lowered.compile().as_text()
+    if program == "refresh_embed":
+        assert text.startswith("HloModule jit_refresh_embed")
+        assert "tpu_custom_call" in text
+    else:
+        assert "tpu_custom_call" not in text
