@@ -182,12 +182,17 @@ def code_mismatch(stored, refresh):
     return int(np.sum((got.astype(bool) != plain) & outside))
 
 
-def feature_gap(prog_rows, ref_rows):
-    """Worst distance between a stored feature and the normalised reference."""
+def feature_row_gaps(prog_rows, ref_rows):
+    """Distance of each stored feature from the normalised reference."""
     ref = np.asarray(ref_rows, np.float64)
     ref = ref / np.linalg.norm(ref, axis=1, keepdims=True)
-    return float(np.max(np.linalg.norm(
-        np.asarray(prog_rows, np.float64) - ref, axis=1)))
+    return [float(g) for g in np.linalg.norm(
+        np.asarray(prog_rows, np.float64) - ref, axis=1)]
+
+
+def feature_gap(prog_rows, ref_rows):
+    """Worst distance between a stored feature and the normalised reference."""
+    return max(feature_row_gaps(prog_rows, ref_rows))
 
 
 def judge(numbers: dict, limits: dict):

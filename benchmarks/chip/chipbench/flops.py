@@ -1,11 +1,8 @@
 """Operations and bytes from shapes, and the chip's peaks.
 
-Model FLOPs follow the usual training count: 6 per matmul parameter per
-trained token (forward, and two for the backward), plus causal attention's
-score and value products, ``6 * S^2 * heads * head_dim`` per sequence and
-layer in training; recomputation does not count.  The embedding gather is
-not a matmul.  Kernel counts are what the algorithm needs for the call at
-its logical shapes, not what a padded layout moves.
+Kernel counts are what the algorithm needs for the call at its logical
+shapes, not what a padded layout moves.  A model's FLOPs per training step
+are its architecture module's (``chipbench/arch/<model_type>.py``).
 """
 
 from __future__ import annotations
@@ -24,20 +21,6 @@ def peaks(device_kind: str) -> dict:
         raise KeyError(f"no peaks for device kind {device_kind!r} in "
                        f"{PEAKS_FILE}; known: {sorted(table)}")
     return table[device_kind]
-
-
-def matmul_params(cfg) -> int:
-    """Matmul parameters one token passes through: layers plus the head."""
-    d, dh = cfg.d_model, cfg.d_head
-    attn = d * cfg.n_heads * dh * 2 + d * cfg.n_kv_heads * dh * 2
-    mlp = 3 * d * cfg.d_ff
-    return cfg.n_layers * (attn + mlp) + d * cfg.vocab
-
-
-def train_flops_per_step(cfg, batch: int, seq: int) -> float:
-    tokens = batch * seq
-    attn = 6 * seq * seq * cfg.n_heads * cfg.d_head * cfg.n_layers * batch
-    return 6.0 * matmul_params(cfg) * tokens + attn
 
 
 def simhash(n: int, d: int, k: int, l: int):

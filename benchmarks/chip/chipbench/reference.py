@@ -1,12 +1,14 @@
 """The plain reference the benchmark's ``correct`` compares against.
 
-Float32 ``jax.numpy`` at the configuration's published widths, written from
-the architecture's description (pre-norm decoder: RMSNorm, GQA attention
-with rotary positions, SwiGLU MLP, untied head) and the training recipe
-(token-mean cross-entropy with importance weights, global-norm clipping,
-Adam under a linear-warm-up cosine schedule).  It imports nothing of the
-program.  Attention runs in blocks of queries and the head in blocks of
-positions, so a 4096-token step fits one chip beside nothing else.
+Float32 ``jax.numpy`` at the configuration's published widths.  The forward
+pass up to the final hidden state is the architecture's own
+(``hidden`` and ``constants`` of ``chipbench/arch/<model_type>.py``, each
+function here takes that module as ``arch``); this file holds what every
+architecture shares: the final RMSNorm and untied head, and the training
+recipe (token-mean cross-entropy with importance weights, global-norm
+clipping, Adam under a linear-warm-up cosine schedule).  It imports nothing
+of the program.  The head runs in blocks of positions, so a 4096-token step
+fits one chip beside nothing else.
 
 ``mm`` carries every matrix product.  ``precision`` names what its
 operands, in the forward and the backward pass, are rounded to:
@@ -26,7 +28,6 @@ import numpy as np
 
 F32 = jnp.float32
 _FP8_MAX = 448.0
-ATTN_BLOCK = 512
 HEAD_BLOCK = 256
 
 
@@ -80,68 +81,9 @@ def rms(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 
 
-def rotary(x, theta):
-    """Rotate-half rotary embedding over the whole head; x: (B, S, H, D)."""
-    s, d = x.shape[1], x.shape[-1]
-    half = d // 2
-    freqs = theta ** (-jnp.arange(half, dtype=F32) / half)
-    ang = jnp.arange(s, dtype=F32)[:, None] * freqs          # (S, half)
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def attention(p, x, c, precision):
-    b, s, _ = x.shape
-    hq, hkv, dh = c["heads"], c["kv_heads"], c["head_dim"]
-    g = hq // hkv
-    h = rms(x, p["norm"]["scale"], c["eps"])
-    q = rotary(mm("bsd,dhk->bshk", h, p["wq"], precision), c["theta"])
-    k = rotary(mm("bsd,dhk->bshk", h, p["wk"], precision), c["theta"])
-    v = mm("bsd,dhk->bshk", h, p["wv"], precision)
-    blk = min(ATTN_BLOCK, s)
-    nb = s // blk
-    qb = q.reshape(b, nb, blk, hkv, g, dh).transpose(1, 0, 3, 4, 2, 5)
-    kpos = jnp.arange(s)
-
-    @jax.checkpoint
-    def one(args):
-        qi, i = args                                  # (B, Hkv, G, blk, D)
-        sc = mm("bhgqd,bkhd->bhgqk", qi, k, precision) * dh ** -0.5
-        qpos = i * blk + jnp.arange(blk)
-        sc = jnp.where(qpos[:, None] >= kpos[None, :], sc, -jnp.inf)
-        w = jax.nn.softmax(sc, axis=-1)
-        return mm("bhgqk,bkhd->bhgqd", w, v, precision)
-
-    o = jax.lax.map(one, (qb, jnp.arange(nb)))       # (nb, B, Hkv, G, blk, D)
-    o = o.transpose(1, 0, 4, 2, 3, 5).reshape(b, s, hq, dh)
-    return x + mm("bshk,hkd->bsd", o, p["wo"], precision)
-
-
-def mlp(p, x, c, precision):
-    h = rms(x, p["norm"]["scale"], c["eps"])
-    gate = mm("bsd,df->bsf", h, p["w_gate"], precision)
-    up = mm("bsd,df->bsf", h, p["w_up"], precision)
-    return x + mm("bsf,fd->bsd", jax.nn.silu(gate) * up, p["w_down"],
-                  precision)
-
-
-def hidden(params, tokens, c, precision):
-    """Final hidden states (before the final norm), (B, S, d) f32."""
-    x = params["embed_group"]["embed"].astype(F32)[tokens]
-    layers = params["blocks"][0]
-    for i in range(c["layers"]):
-        lp = jax.tree.map(lambda a: a[i].astype(F32), layers)
-        x = jax.checkpoint(
-            lambda x, lp: mlp(lp["ffn"], attention(lp["attn"], x, c,
-                                                   precision), c, precision)
-        )(x, lp)
-    return x
-
-
-def loss(params, batch, c, precision):
+def loss(arch, params, batch, c, precision):
     """Token-mean cross-entropy, each row scaled by its importance weight."""
-    h = hidden(params, batch["tokens"], c, precision)
+    h = arch.hidden(params, batch["tokens"], c, precision)
     eg = params["embed_group"]
     h = rms(h, eg["final_norm"]["scale"], c["eps"])
     b, s, d = h.shape
@@ -164,14 +106,14 @@ def loss(params, batch, c, precision):
     return jnp.sum(per_row) / (b * s)
 
 
-def pooled(params, tokens, c, precision):
+def pooled(arch, params, tokens, c, precision):
     """Mean over positions of the final hidden state: one row's feature."""
-    return jnp.mean(hidden(params, tokens, c, precision), axis=1)
+    return jnp.mean(arch.hidden(params, tokens, c, precision), axis=1)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def pooled_jit(params, tokens, c, precision):
-    return pooled(params, tokens, dict(c), precision)
+@functools.partial(jax.jit, static_argnums=(0, 3, 4))
+def pooled_jit(arch, params, tokens, c, precision):
+    return pooled(arch, params, tokens, dict(c), precision)
 
 
 def lr_at(step, o):
@@ -184,11 +126,13 @@ def lr_at(step, o):
     return 0.5 * o["lr"] * (1 + math.cos(math.pi * frac))
 
 
-@functools.partial(jax.jit, static_argnames=("c", "precision", "dtypes"),
+@functools.partial(jax.jit,
+                   static_argnames=("arch", "c", "precision", "dtypes"),
                    donate_argnums=(0, 1, 2))
-def _adam_step(params, m, v, batch, lr, t, clip, c, precision, dtypes):
+def _adam_step(params, m, v, batch, lr, t, clip, arch, c, precision, dtypes):
     c = dict(c)
-    val, g = jax.value_and_grad(loss)(params, batch, c, precision)
+    val, g = jax.value_and_grad(loss, argnums=1)(arch, params, batch, c,
+                                                 precision)
     gnorm = jnp.sqrt(sum(jnp.sum(x * x) for x in jax.tree.leaves(g)))
     g = jax.tree.map(lambda x: x * jnp.minimum(1.0, clip / jnp.maximum(gnorm, 1e-9)), g)
     b1, b2, eps = 0.9, 0.999, 1e-8
@@ -205,22 +149,15 @@ def _adam_step(params, m, v, batch, lr, t, clip, c, precision, dtypes):
     return params, m, v, val, norms
 
 
-def model_constants(cfg):
-    """The sizes the reference reads, as a hashable tuple of pairs."""
-    return tuple(sorted({
-        "heads": cfg.n_heads, "kv_heads": cfg.n_kv_heads,
-        "head_dim": cfg.d_head, "eps": cfg.norm_eps,
-        "theta": cfg.rope_theta, "layers": cfg.n_layers}.items()))
-
-
-def train_steps(params0, batches, cfg, opt, precision="float32"):
-    """Follow the program's first steps from the same weights and batches.
+def train_steps(arch, params0, batches, cfg, opt, precision="float32"):
+    """Follow the program's first steps from the same weights and batches,
+    through ``arch``'s forward.
 
     Returns the loss of each step, the clipped gradient's per-leaf norms at
     step 1 and the per-leaf norms of the change of the parameters after the
     last step, all as numpy.
     """
-    c = model_constants(cfg)
+    c = arch.constants(cfg)
     p = jax.tree.map(lambda x: jnp.array(x, F32, copy=True), params0)
     m = jax.tree.map(jnp.zeros_like, p)
     v = jax.tree.map(jnp.zeros_like, p)
@@ -229,7 +166,7 @@ def train_steps(params0, batches, cfg, opt, precision="float32"):
         b = {k: jnp.asarray(x) for k, x in b.items()}
         p, m, v, val, norms = _adam_step(
             p, m, v, b, lr_at(i, opt), float(i + 1), float(opt["clip"]),
-            c=c, precision=precision,
+            arch=arch, c=c, precision=precision,
             dtypes=tuple(str(x.dtype) for x in jax.tree.leaves(params0)))
         losses.append(float(val))
         if grad_norms is None:

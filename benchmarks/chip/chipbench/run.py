@@ -33,8 +33,7 @@ from repro.launch.mesh import make_host_mesh
 
 from . import check, reference, spec
 from .build import build_trainer
-from .traffic import (PARAMS, PIPELINE, corpus_for, make_params, params_on,
-                      stream)
+from .traffic import PARAMS, PIPELINE, corpus_for, params_on, stream
 
 CHUNK_SECONDS = 2.0     # length of one Trainer.run call in a cell without
 #                         refreshes; a cell with refreshes runs whole periods
@@ -213,16 +212,17 @@ def reference_numbers(cell, cfg, seed, prog, control=None, detail=None):
         if "draw_state" in prog:
             batches[-1]["loss_weights"] = check.plain_weights(
                 prog["draws"][i], prog["draw_state"]).astype(np.float32)
-    start = make_params(stream(seed, PARAMS), cfg)
+    start = cell.arch.make_params(stream(seed, PARAMS), cfg)
     prog = {**prog, "change_norms": np.asarray([
         float(n) for n in _change_norms(prog["params"], start)])}
     ref = dict(zip(("losses", "grad_norms", "change_norms"),
-                   reference.train_steps(start, batches, cfg, _opt(traffic))))
+                   reference.train_steps(cell.arch, start, batches, cfg,
+                                         _opt(traffic))))
     got = prog
     if control:
         got = dict(zip(("losses", "grad_norms", "change_norms"),
-                       reference.train_steps(start, batches, cfg,
-                                             _opt(traffic), control)))
+                       reference.train_steps(cell.arch, start, batches,
+                                             cfg, _opt(traffic), control)))
     del start
     gc.collect()
     nums = check.training_gaps(got, ref)
@@ -249,19 +249,21 @@ def reference_numbers(cell, cfg, seed, prog, control=None, detail=None):
         tokens = corpus[r["rows"], :-1]
         rows = r["row_features"]
         if control:
-            rows = pooled_rows(r["params"], tokens, cfg, control)
+            rows = pooled_rows(cell.arch, r["params"], tokens, cfg, control)
             rows = rows / np.linalg.norm(rows, axis=1, keepdims=True)
-        nums["feature_gap"] = check.feature_gap(
-            rows, pooled_rows(r["params"], tokens, cfg, "float32"))
+        ref_rows = pooled_rows(cell.arch, r["params"], tokens, cfg, "float32")
+        nums["feature_gap"] = check.feature_gap(rows, ref_rows)
+        if detail is not None:
+            detail["feature_row_gaps"] = check.feature_row_gaps(rows, ref_rows)
     return nums
 
 
-def pooled_rows(params_host, tokens, cfg, precision):
+def pooled_rows(arch, params_host, tokens, cfg, precision):
     """The reference's feature of each row, one row per call."""
     p = jax.tree.map(jnp.asarray, params_host)
-    c = reference.model_constants(cfg)
+    c = arch.constants(cfg)
     return np.stack([_host(reference.pooled_jit(
-        p, jnp.asarray(t[None]), c, precision)[0]) for t in tokens])
+        arch, p, jnp.asarray(t[None]), c, precision)[0]) for t in tokens])
 
 
 def set_up(cell, seed, mesh, clock=None):
@@ -271,12 +273,12 @@ def set_up(cell, seed, mesh, clock=None):
     cfg = spec.model_config(cell.config, cell.config_name)
     traffic = cell.traffic
     tokens, hard = corpus_for(seed, cfg, traffic)
-    shapes = jax.eval_shape(lambda k: make_params(k, cfg),
+    shapes = jax.eval_shape(lambda k: cell.arch.make_params(k, cfg),
                             stream(seed, PARAMS))
     shardings = tree_param_shardings(shapes, mesh)
     tr = build_trainer(
         cfg, traffic, tokens, hard,
-        params_on(stream(seed, PARAMS), cfg, shardings), mesh,
+        params_on(cell.arch, stream(seed, PARAMS), cfg, shardings), mesh,
         pipeline_key=stream(seed, PIPELINE), uniform_seed=seed,
         step_hook=clock)
     del tokens, hard

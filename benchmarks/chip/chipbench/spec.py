@@ -1,10 +1,11 @@
 """What one cell is: its entry in ``BENCHMARK.json`` and the files it names.
 
-A cell names a configuration (``configs/<config>.json``) and a traffic mix
-(``traffic/<traffic>.json``); its correctness limits sit in
-``limits/<cell>.json`` and each metric it reports is read by
-``metrics/<metric>.py``.  Nothing here knows a cell by name, so a new cell
-is new files and a new ``workloads`` entry.
+A cell names a configuration (``configs/<config>.json``, whose
+``model_type`` names its architecture module ``chipbench/arch/<type>.py``)
+and a traffic mix (``traffic/<traffic>.json``); its correctness limits sit
+in ``limits/<cell>.json`` and each metric it reports is read by
+``metrics/<metric>.py``.  Nothing here knows a cell or an architecture by
+name, so a new cell is new files and a new ``workloads`` entry.
 """
 
 from __future__ import annotations
@@ -13,22 +14,15 @@ import dataclasses
 import json
 import os
 
+from . import arch as archs
+
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 
-# HF config.json key -> ModelConfig field, for the keys the model reads
-_MODEL_KEYS = {
-    "hidden_size": "d_model",
-    "intermediate_size": "d_ff",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "head_dim": "d_head",
-    "num_hidden_layers": "n_layers",
-    "vocab_size": "vocab",
-    "rms_norm_eps": "norm_eps",
-    "rope_theta": "rope_theta",
-}
-_ACTS = {"silu": "swiglu"}
+# keys that say where a configuration comes from and how it was cut, or how
+# the program executes it (``execution``: ModelConfig's execution fields)
+DESCRIBING = ("source", "model_type", "published", "deployment",
+              "departures", "assumed", "execution")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,6 +31,7 @@ class Cell:
     chips: int
     config_name: str
     config: dict          # the configuration file as run
+    arch: object          # its architecture module (chipbench.arch.<type>)
     traffic_name: str
     traffic: dict         # the traffic mix's parameters
     end_to_end: list      # BENCHMARK.json metric entries this cell reports
@@ -60,9 +55,14 @@ def load_cell(name, bench_path=None):
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
     w = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    config = read_json(os.path.join(ROOT, conf["file"]))
+    try:
+        model_config(config, w["config"])
+    except ValueError as e:
+        raise SystemExit(f"{conf['file']}: {e}") from None
     return Cell(
         name=name, chips=w["chips"], config_name=w["config"],
-        config=read_json(os.path.join(ROOT, conf["file"])),
+        config=config, arch=archs.load(config["model_type"]),
         traffic_name=w["traffic"],
         traffic=read_json(os.path.join(HERE, "traffic", w["traffic"] + ".json")),
         end_to_end=[m for m in bench["end_to_end"] if _reports(m, name)],
@@ -72,12 +72,26 @@ def load_cell(name, bench_path=None):
 
 
 def model_config(conf: dict, name: str):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.models import ModelConfig
+    """The program's ``ModelConfig`` for a configuration file, built by the
+    module of its ``model_type``.
 
-    kw = {field: conf[key] for key, field in _MODEL_KEYS.items()
-          if key in conf}
-    kw["act"] = _ACTS[conf["hidden_act"]]
-    kw["dtype"] = conf["torch_dtype"]
-    kw.update(conf.get("execution", {}))
-    return ModelConfig(name=name, **kw)
+    Every top-level key is one the module reads (``KEYS``), one it holds at
+    the value the program computes (``FIXED``), or one that describes the
+    file (``DESCRIBING``).  Any other key, or a fixed one at another value,
+    raises ``ValueError`` naming it: the program would run without it.
+    """
+    module = archs.load(conf.get("model_type"))
+    where = f"chipbench/arch/{module.__name__.rsplit('.', 1)[-1]}.py"
+    for key in conf:
+        if key not in DESCRIBING and key not in module.KEYS \
+                and key not in module.FIXED:
+            raise ValueError(f"{name}: key {key!r} is read by nothing: "
+                             f"{where} neither reads nor fixes it")
+    cfg = module.model_config(conf, name)
+    for key, want in module.FIXED.items():
+        want = want(cfg) if callable(want) else want
+        if key in conf and conf[key] != want:
+            raise ValueError(f"{name}: key {key!r} is {conf[key]!r}, but "
+                             f"the program computes only {key} = {want!r} "
+                             f"({where})")
+    return cfg

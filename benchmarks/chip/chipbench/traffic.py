@@ -5,10 +5,11 @@ law over the vocabulary, with a ``hard_frac`` share of rows drawn from the
 same law over a permuted vocabulary.  It is drawn on the device by inverse
 CDF, so a 1024 x 4097 shard costs one small program, not a host loop.
 
-``make_params`` draws the model's weights exactly as the program's
-initialiser lays them out (same key splits, scales and dtype); the
-benchmark's tests pin the two equal, and the reference draws its own copy
-from here, never from the program.
+The model's weights are drawn by the ``make_params`` of the configuration's
+architecture module (``chipbench/arch/<model_type>.py``), exactly as the
+program's initialiser lays them out; the benchmark's tests pin the two
+equal, and the reference draws its own copy from there, never from the
+program.
 """
 
 from __future__ import annotations
@@ -63,58 +64,7 @@ def corpus_for(seed: int, cfg, traffic: dict):
         hard_frac=float(traffic["hard_frac"]))
 
 
-def _normal(key, shape, scale, dtype):
-    return (jax.random.normal(key, shape) * scale).astype(dtype)
-
-
-def _block(key, cfg):
-    d, dh, hq, hkv, ff = (cfg.d_model, cfg.d_head, cfg.n_heads,
-                          cfg.n_kv_heads, cfg.d_ff)
-    dt = jnp.dtype(cfg.dtype)
-    ka, _, kf = jax.random.split(key, 3)
-    kq, kk, kv, ko = jax.random.split(ka, 4)
-    s = d ** -0.5
-    ones = {"scale": jnp.ones((d,), jnp.float32)}
-    kg, ku, kd = jax.random.split(kf, 3)
-    return {
-        "attn": {
-            "norm": ones,
-            "wq": _normal(kq, (d, hq, dh), s, dt),
-            "wk": _normal(kk, (d, hkv, dh), s, dt),
-            "wv": _normal(kv, (d, hkv, dh), s, dt),
-            "wo": _normal(ko, (hq, dh, d), s * 0.5, dt),
-        },
-        "ffn": {
-            "norm": ones,
-            "w_up": _normal(ku, (d, ff), s, dt),
-            "w_down": _normal(kd, (ff, d), ff ** -0.5, dt),
-            "w_gate": _normal(kg, (d, ff), s, dt),
-        },
-    }
-
-
-def make_params(key, cfg):
-    """Weights of a dense SwiGLU decoder with one attention block per layer,
-    in the layout and dtype the program trains (layers stacked on axis 0)."""
-    if cfg.block_pattern != ("attn",) or cfg.act != "swiglu" or cfg.is_moe:
-        raise ValueError(f"{cfg.name}: only dense SwiGLU attention stacks")
-    k_embed, k_blocks, _ = jax.random.split(key, 3)
-    ke, kh = jax.random.split(k_embed)
-    d, v = cfg.d_model, cfg.vocab
-    dt = jnp.dtype(cfg.dtype)
-    blocks = jax.vmap(lambda k: _block(k, cfg))(
-        jax.random.split(k_blocks, cfg.n_layers))
-    return {
-        "embed_group": {
-            "embed": _normal(ke, (v, d), d ** -0.5, dt),
-            "lm_head": _normal(kh, (d, v), d ** -0.5, dt),
-            "final_norm": {"scale": jnp.ones((d,), jnp.float32)},
-        },
-        "blocks": [blocks],
-    }
-
-
-def params_on(key, cfg, shardings):
-    """``make_params`` as one jitted call straight into ``shardings``."""
-    return jax.jit(make_params, static_argnums=1,
+def params_on(arch, key, cfg, shardings):
+    """``arch.make_params`` as one jitted call straight into ``shardings``."""
+    return jax.jit(arch.make_params, static_argnums=1,
                    out_shardings=shardings)(key, cfg)

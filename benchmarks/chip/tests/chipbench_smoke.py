@@ -18,11 +18,12 @@ for p in (os.path.join(ROOT, "src"), BENCH):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from chipbench import spec  # noqa: E402
+from chipbench import arch, spec  # noqa: E402
 
 CONFIG = {
-    "hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
-    "num_key_value_heads": 2, "head_dim": 16, "num_hidden_layers": 2,
+    "model_type": "granite", "hidden_size": 64, "intermediate_size": 128,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "num_hidden_layers": 2,
     "vocab_size": 128, "hidden_act": "silu", "rms_norm_eps": 1e-5,
     "rope_theta": 500000.0, "torch_dtype": "float32",
     "execution": {"attn_block_q": 16, "loss_chunk": 16},
@@ -49,6 +50,7 @@ def cell(kind):
                   "feature_gap"):
             limits.pop(k)
     return spec.Cell(name=f"smoke-{kind}", chips=1, config_name="smoke",
-                     config=CONFIG, traffic_name=kind,
-                     traffic=TRAFFIC[kind], end_to_end=METRICS,
+                     config=CONFIG, arch=arch.load("granite"),
+                     traffic_name=kind, traffic=TRAFFIC[kind],
+                     end_to_end=METRICS,
                      per_layer=[], limits=limits)

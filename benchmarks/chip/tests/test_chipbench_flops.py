@@ -1,16 +1,18 @@
-"""FLOP and byte counts of the chip benchmark, from shapes alone."""
+"""FLOP and byte counts of the chip benchmark, from shapes alone: a model's
+from its architecture module, a kernel's from ``chipbench/flops.py``."""
 
 import json
 
 import pytest
 
 import chipbench_smoke as cs
-from chipbench import flops, spec
+from chipbench import arch, flops, spec
 
 
 def config(name):
-    return spec.model_config(
-        spec.read_json(f"{cs.BENCH}/configs/{name}.json"), name)
+    """(architecture module, ModelConfig) of a configuration file."""
+    conf = spec.read_json(f"{cs.BENCH}/configs/{name}.json")
+    return arch.load(conf["model_type"]), spec.model_config(conf, name)
 
 
 @pytest.mark.parametrize("name, n_matmul", [
@@ -18,16 +20,16 @@ def config(name):
     ("phi4-mini-1chip", 254.3e6),
 ])
 def test_matmul_params(name, n_matmul):
-    assert flops.matmul_params(config(name)) == pytest.approx(n_matmul,
-                                                             rel=1e-3)
+    module, cfg = config(name)
+    assert module.matmul_params(cfg) == pytest.approx(n_matmul, rel=1e-3)
 
 
 def test_train_flops_granite_step():
     # 6 N T plus causal attention: about 20.5 TFLOP per 2 x 4096 step
-    cfg = config("granite-3-8b-1chip")
-    per_step = flops.train_flops_per_step(cfg, 2, 4096)
+    module, cfg = config("granite-3-8b-1chip")
+    per_step = module.train_flops_per_step(cfg, 2, 4096)
     attn = 6 * 4096 ** 2 * 32 * 128 * 2
-    assert per_step == 6 * flops.matmul_params(cfg) * 8192 + attn
+    assert per_step == 6 * module.matmul_params(cfg) * 8192 + attn
     assert per_step == pytest.approx(20.5e12, rel=0.01)
 
 

@@ -13,6 +13,7 @@ import pytest
 
 import chipbench_smoke as cs
 from chipbench import build, spec, traffic
+from chipbench.arch import granite
 from repro.configs import granite_3_8b, phi4_mini_3_8b
 from repro.data import make_token_corpus
 from repro.dist.sharding import tree_param_shardings, use_mesh
@@ -36,7 +37,7 @@ def test_configuration_files_are_the_program_configs(name, expected):
 def test_weights_equal_init_params(dtype):
     cfg = granite_3_8b.SMOKE.with_(dtype=dtype)
     key = jax.random.PRNGKey(7)
-    got, want = traffic.make_params(key, cfg), init_params(key, cfg)
+    got, want = granite.make_params(key, cfg), init_params(key, cfg)
     assert jax.tree.structure(got) == jax.tree.structure(want)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert a.dtype == b.dtype
@@ -46,7 +47,7 @@ def test_weights_equal_init_params(dtype):
 def test_weights_match_layout_at_real_size():
     cfg = granite_3_8b.ONE_CHIP
     key = jax.random.PRNGKey(0)
-    got = jax.eval_shape(lambda k: traffic.make_params(k, cfg), key)
+    got = jax.eval_shape(lambda k: granite.make_params(k, cfg), key)
     want = jax.eval_shape(lambda k: init_params(k, cfg), key)
     assert jax.tree.map(lambda x: (x.shape, x.dtype), got) == \
         jax.tree.map(lambda x: (x.shape, x.dtype), want)
@@ -85,7 +86,7 @@ def test_first_step_loss_equals_train(lgd):
            "lr": 1e-3, "warmup_steps": 10, "schedule_steps": 0}
     data = make_token_corpus(0, corpus, seq, cfg.vocab)
     with use_mesh(mesh):
-        params = traffic.make_params(jax.random.PRNGKey(0), cfg)
+        params = granite.make_params(jax.random.PRNGKey(0), cfg)
         shardings = tree_param_shardings(params, mesh)
         params = jax.tree.map(jax.device_put, params, shardings)
         tr = build.build_trainer(
