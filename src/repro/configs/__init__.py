@@ -24,6 +24,7 @@ ARCHS: List[str] = [
     "musicgen_large",
     "llama_3_2_vision_90b",
     "zamba2_1_2b",
+    "granite_4_0_h_micro",
 ]
 
 # accepted CLI aliases (--arch with dashes/dots)

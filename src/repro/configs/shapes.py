@@ -124,8 +124,11 @@ def cache_specs(cfg: ModelConfig, shape: ShapeSpec) -> list:
         elif kind == "mamba2":
             d_inner = cfg.ssm_expand * cfg.d_model
             nh = d_inner // cfg.ssm_head_dim
-            out.append({"state": _f(
-                (r, b, nh, cfg.ssm_state, cfg.ssm_head_dim))})
+            out.append({"state": {
+                "ssm": _f((r, b, nh, cfg.ssm_state, cfg.ssm_head_dim)),
+                "conv": _f((r, b, cfg.ssm_conv - 1,
+                            d_inner + 2 * cfg.ssm_state), dt),
+            }})
         elif kind == "mlstm":
             dh = cfg.d_model // cfg.n_heads
             out.append({"state": _f((r, b, cfg.n_heads, dh, dh + 1))})

@@ -68,13 +68,6 @@ def active_params(cfg: ModelConfig) -> float:
             blk += d * dh * (cfg.n_heads * 2 + cfg.n_kv_heads * 2)  # qkvo
             if kind == "cross_attn":
                 blk *= 2
-            if cfg.is_moe:
-                n_mats = 3
-                blk += d * cfg.moe_experts  # router (all tokens)
-                blk += cfg.moe_top_k * n_mats * d * cfg.moe_d_ff
-            elif cfg.d_ff:
-                n_mats = 3 if cfg.act == "swiglu" else 2
-                blk += n_mats * d * cfg.d_ff
         elif kind == "mamba2":
             d_inner = cfg.ssm_expand * d
             nh = d_inner // cfg.ssm_head_dim
@@ -84,6 +77,15 @@ def active_params(cfg: ModelConfig) -> float:
             blk += d * 3 * d + d * 2 * cfg.n_heads + d * d
         elif kind == "slstm":
             blk += d * 4 * d + d * d
+        if kind in ("attn", "shared_attn", "cross_attn") or (
+                kind == "mamba2" and cfg.ssm_ffn):
+            if cfg.is_moe:
+                n_mats = 3
+                blk += d * cfg.moe_experts  # router (all tokens)
+                blk += cfg.moe_top_k * n_mats * d * cfg.moe_d_ff
+            elif cfg.d_ff:
+                n_mats = 3 if cfg.act == "swiglu" else 2
+                blk += n_mats * d * cfg.d_ff
         per_pattern += cnt * blk
     total = per_pattern * cfg.repeats
     total += 2 * cfg.vocab * d          # embed + head

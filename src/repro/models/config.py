@@ -39,6 +39,8 @@ class ModelConfig:
     ssm_state: int = 0                # Mamba2 state size N
     ssm_head_dim: int = 64            # Mamba2/mLSTM head dim P
     ssm_expand: int = 2               # d_inner = expand * d_model
+    ssm_conv: int = 4                 # Mamba2 causal depthwise conv width
+    ssm_ffn: bool = False             # mamba2 layers carry the FFN too
     chunk: int = 256                  # chunked-scan length for SSM/linear attn
 
     # modality frontend: "none" = token ids; "embed_stub" = precomputed
@@ -47,7 +49,15 @@ class ModelConfig:
     n_patches: int = 0                # vision: image patch count (stub)
 
     rope_theta: float = 500000.0
+    rope: bool = True                 # rotary positions (False: NoPE)
     norm_eps: float = 1e-5
+
+    # Granite-style scalings: the embedding output, every mixer and FFN
+    # branch before its residual add, and the attention score scale
+    # (0 -> d_head^-0.5)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
     dtype: str = "bfloat16"
 
     # execution knobs

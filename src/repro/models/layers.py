@@ -35,6 +35,13 @@ def rms_norm(p, x: jax.Array, eps: float) -> jax.Array:
     return (xf * jax.lax.rsqrt(var + eps) * p["scale"]).astype(x.dtype)
 
 
+def residual(cfg: ModelConfig, x: jax.Array, branch: jax.Array) -> jax.Array:
+    """``x + branch``, the branch scaled by ``cfg.residual_multiplier``."""
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * cfg.residual_multiplier
+    return x + branch
+
+
 # ---------------------------------------------------------------------------
 # RoPE
 # ---------------------------------------------------------------------------
@@ -91,6 +98,9 @@ def attention(
     # shared by the q/k/v projections — instead of one per einsum.
     h = logical(h, "batch", None, None)
     q = jnp.einsum("bsd,dhk->bshk", h, p["wq"])
+    if cfg.attention_multiplier:
+        # the kernels scale scores by d_head^-0.5: fold the rest into q
+        q = q * (cfg.attention_multiplier * cfg.d_head ** 0.5)
     q = logical(q, "batch", None, "heads", None)
     src = h if kv is None else kv      # memory (e.g. image patch embeds)
     k = jnp.einsum("bsd,dhk->bshk", src, p["wk"])
@@ -103,7 +113,7 @@ def attention(
     impl = cfg.attn_impl if kv is None else "ref"
     if cache is None or x.shape[1] > 1:
         # full-sequence path (training, or prefill writing into the cache)
-        if kv is None:   # self attention with rope
+        if kv is None and cfg.rope:   # self attention with rope
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
         if impl == "chunked":
@@ -127,8 +137,9 @@ def attention(
     else:
         # single-token decode: append to cache, flash-decode over it
         assert x.shape[1] == 1
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        if cfg.rope:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
         b = x.shape[0]
         idx = cache["len"]             # (B,) current lengths
         k_cache = cache["k"].at[jnp.arange(b), idx].set(k[:, 0])
@@ -140,7 +151,7 @@ def attention(
 
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     out = logical(out, "batch", None, None)
-    return x + out, new_cache
+    return residual(cfg, x, out), new_cache
 
 
 def init_attention_cache(cfg: ModelConfig, batch: int, max_len: int):
@@ -185,7 +196,7 @@ def mlp(p, cfg: ModelConfig, x: jax.Array) -> jax.Array:
         act = jax.nn.gelu(up)
     out = jnp.einsum("bsf,fd->bsd", act, p["w_down"])
     out = logical(out, "batch", None, None)
-    return x + out
+    return residual(cfg, x, out)
 
 
 # ---------------------------------------------------------------------------

@@ -68,10 +68,10 @@ def _init_block(key, cfg: ModelConfig, kind: str) -> Dict[str, Any]:
     else:
         raise ValueError(kind)
     # FFN: attention-style blocks carry the MLP/MoE; pure mixers don't,
-    # except when the config gives them an FFN (d_ff>0 and kind=="mamba2"
-    # in hybrid archs is still FFN-free — Zamba puts the FFN in the shared
-    # block only).
-    if kind in ATTN_KINDS and (cfg.is_moe or cfg.d_ff > 0):
+    # except mamba2 blocks under ``cfg.ssm_ffn`` (Granite-4.0-H puts an
+    # MLP in every layer; Zamba only in its shared block).
+    with_ffn = kind in ATTN_KINDS or (kind == "mamba2" and cfg.ssm_ffn)
+    if with_ffn and (cfg.is_moe or cfg.d_ff > 0):
         p["ffn"] = init_moe(kf, cfg) if cfg.is_moe else init_mlp(kf, cfg)
     return p
 
@@ -107,7 +107,7 @@ def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
 # block application
 # ---------------------------------------------------------------------------
 
-def _apply_block(
+def _apply_mixer(
     kind: str,
     p,
     cfg: ModelConfig,
@@ -117,7 +117,7 @@ def _apply_block(
     cache_entry,
     decode: bool,
 ):
-    """Returns (x, new_cache_entry)."""
+    """The block's sequence mixer.  Returns (x, new_cache_entry)."""
     new_cache = cache_entry
     if kind in ("attn", "shared_attn", "cross_attn"):
         att_cache = None if cache_entry is None else cache_entry["attn"]
@@ -151,21 +151,32 @@ def _apply_block(
             new_cache = {"state": st}
     else:
         raise ValueError(kind)
+    return x, new_cache
 
+
+def _apply_ffn(p, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    """The block's FFN, where it has one."""
     if "ffn" in (p or {}):
         x = moe_ffn(p["ffn"], cfg, x) if cfg.is_moe else mlp(p["ffn"], cfg, x)
-    return x, new_cache
+    return x
 
 
 # ---------------------------------------------------------------------------
 # full-sequence forward (train / prefill)
 # ---------------------------------------------------------------------------
 
-def _inputs(params, cfg: ModelConfig, batch: Dict[str, jax.Array]):
+def _embed(params, cfg: ModelConfig, batch):
     if cfg.frontend == "embed_stub":
         x = batch["embeds"].astype(jnp.dtype(cfg.dtype))
     else:
         x = embed_tokens(params["embed_group"], batch["tokens"])
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
+def _inputs(params, cfg: ModelConfig, batch: Dict[str, jax.Array]):
+    x = _embed(params, cfg, batch)
     image_mem = batch.get("image_embeds")
     if image_mem is not None:
         image_mem = image_mem.astype(x.dtype)
@@ -174,29 +185,78 @@ def _inputs(params, cfg: ModelConfig, batch: Dict[str, jax.Array]):
     return x, image_mem, positions
 
 
+def _remat_in_order(apply):
+    """``apply(p, x, aux) -> x`` rematerialised: its backward recomputes the
+    forward from (p, x) once the output's cotangent has arrived; ``aux``
+    (positions, image memory) is not differentiated.  Under
+    ``jax.checkpoint`` nothing orders a block's recomputation after the
+    backward of the blocks above it, and XLA schedules the recomputations
+    of a whole pattern early, holding every block's intermediates at once;
+    the optimization barrier ties each to its cotangent (and keeps it from
+    being merged with the forward pass)."""
+    @jax.custom_vjp
+    def f(p, x, aux):
+        return apply(p, x, aux)
+
+    def fwd(p, x, aux):
+        return apply(p, x, aux), (p, x, aux)
+
+    def bwd(res, g):
+        (p, x, aux), g = jax.lax.optimization_barrier((res, g))
+        _, vjp = jax.vjp(lambda p, x: apply(p, x, aux), p, x)
+        return (*vjp(g), jax.tree.map(lambda a: None, aux))
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
 def _scan_blocks(params, cfg: ModelConfig, x, positions, image_mem,
                  cache, decode: bool):
-    """lax.scan over repeats; python loop over pattern positions inside."""
+    """lax.scan over repeats; python loop over pattern positions inside.
+
+    With ``cfg.remat`` the backward pass holds one unit's intermediates at
+    a time.  A one-block pattern rematerialises each block, ordered by the
+    scan; a longer one each block's mixer and FFN apart, ordered by
+    ``_remat_in_order``: a pattern's layers sit in one scan step, and at
+    long sequences a whole block's intermediates are several GB."""
     shared = params.get("shared")
+    ordered = len(cfg.block_pattern) > 1 and cache is None
+    aux = (positions, image_mem)
+
+    def block(kind):
+        def mixer(pj, xx, cj, aux):
+            if cfg.seq_shard and not decode:
+                xx = logical(xx, "batch", "seq", None)
+            return _apply_mixer(kind, pj, cfg, xx, *aux, cj, decode)
+
+        def apply(pj, xx, cj):
+            xx, cj = mixer(pj, xx, cj, aux)
+            return _apply_ffn(pj, cfg, xx), cj
+        if not cfg.remat or decode:
+            return apply
+        if not ordered:
+            return jax.checkpoint(apply)
+        mix = _remat_in_order(lambda pm, xx, a: mixer(pm, xx, None, a)[0])
+        ffn = _remat_in_order(lambda pf, xx, a: _apply_ffn(pf, cfg, xx))
+
+        def units(pj, xx, cj):
+            pm = {k: v for k, v in pj.items() if k != "ffn"}
+            pf = {k: v for k, v in pj.items() if k == "ffn"}
+            return ffn(pf, mix(pm, xx, aux), ()), None
+        return units
 
     def body(xc, xs):
         xx, _ = xc
-        if cfg.seq_shard and not decode:
-            xx = logical(xx, "batch", "seq", None)
         rep_params, rep_cache = xs
         new_rep_cache = []
         for j, kind in enumerate(cfg.block_pattern):
             pj = shared if kind == "shared_attn" else rep_params[j]
             cj = None if rep_cache is None else rep_cache[j]
-            xx, cj_new = _apply_block(
-                kind, pj, cfg, xx, positions, image_mem, cj, decode)
+            xx, cj_new = block(kind)(pj, xx, cj)
             new_rep_cache.append(cj_new)
         if rep_cache is None:
             return (xx, None), None
         return (xx, None), new_rep_cache
-
-    if cfg.remat and not decode:
-        body = jax.checkpoint(body)
 
     # xs pytrees: blocks list with leading dim = repeats (None for shared)
     xs_params = [
@@ -326,10 +386,7 @@ def decode_hidden(params, cfg: ModelConfig, batch, cache):
     heads (e.g. the LSH-shortlisted head in ``models.sampled_softmax``)
     can reuse the unchanged block stack without paying the O(V) logits
     matmul.  Returns (hidden (B, 1, d), new_cache)."""
-    if cfg.frontend == "embed_stub":
-        x = batch["embeds"].astype(jnp.dtype(cfg.dtype))
-    else:
-        x = embed_tokens(params["embed_group"], batch["tokens"])
+    x = _embed(params, cfg, batch)
     image_mem = batch.get("image_embeds")
     if image_mem is not None:
         image_mem = image_mem.astype(x.dtype)

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from repro.dist.sharding import logical
 from .config import ModelConfig
-from .layers import init_rmsnorm, rms_norm
+from .layers import init_rmsnorm, residual, rms_norm
 
 
 def init_moe(key, cfg: ModelConfig):
@@ -108,7 +108,7 @@ def moe_ffn(p, cfg: ModelConfig, x: jax.Array) -> jax.Array:
         lambda ob, inf: _combine_one_group(ob, inf, s, d, h.dtype)
     )(out_buf, info)
     out = logical(out, "batch", None, None)
-    return x + out
+    return residual(cfg, x, out)
 
 
 def aux_load_balance_loss(p, cfg: ModelConfig, x: jax.Array) -> jax.Array:

@@ -20,6 +20,7 @@ cost per token — the reason these archs run the long_500k shape.
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -27,7 +28,7 @@ import jax.numpy as jnp
 
 from repro.dist.sharding import logical
 from .config import ModelConfig
-from .layers import init_rmsnorm, rms_norm
+from .layers import init_rmsnorm, residual, rms_norm
 
 
 # ---------------------------------------------------------------------------
@@ -35,16 +36,20 @@ from .layers import init_rmsnorm, rms_norm
 # ---------------------------------------------------------------------------
 
 def gla_chunked(
-    q: jax.Array,        # (B, S, H, N)  query / C in mamba2
-    k: jax.Array,        # (B, S, H, N)  key   / B in mamba2
+    q: jax.Array,        # (B, S, H or 1, N)  query / C in mamba2
+    k: jax.Array,        # (B, S, H or 1, N)  key   / B in mamba2
     v: jax.Array,        # (B, S, H, P)  value / x in mamba2
     log_a: jax.Array,    # (B, S, H)     per-step log decay (<= 0)
     chunk: int,
     state0: Optional[jax.Array] = None,   # (B, H, N, P)
 ) -> Tuple[jax.Array, jax.Array]:
-    """Returns (y (B,S,H,P), state (B,H,N,P))."""
-    b, s, h, n = q.shape
-    p = v.shape[-1]
+    """Returns (y (B,S,H,P), state (B,H,N,P)).
+
+    ``q`` and ``k`` with one head are shared by all H heads of ``v``
+    (Mamba-2's single B/C group): their (c, c) products are computed once
+    per chunk, not once per head."""
+    b, s, hk, n = q.shape
+    h, p = v.shape[2], v.shape[-1]
     c = min(chunk, s)
     s_orig = s
     if s % c != 0:
@@ -57,8 +62,8 @@ def gla_chunked(
         s = s + pad
     nc = s // c
 
-    qc = q.reshape(b, nc, c, h, n).transpose(1, 0, 3, 2, 4)  # (nc,B,H,c,N)
-    kc = k.reshape(b, nc, c, h, n).transpose(1, 0, 3, 2, 4)
+    qc = q.reshape(b, nc, c, hk, n).transpose(1, 0, 3, 2, 4)  # (nc,B,hk,c,N)
+    kc = k.reshape(b, nc, c, hk, n).transpose(1, 0, 3, 2, 4)
     vc = v.reshape(b, nc, c, h, p).transpose(1, 0, 3, 2, 4)  # (nc,B,H,c,P)
     la = log_a.reshape(b, nc, c, h).transpose(1, 0, 3, 2)    # (nc,B,H,c)
 
@@ -67,6 +72,7 @@ def gla_chunked(
 
     if state0 is None:
         state0 = jnp.zeros((b, h, n, p), jnp.float32)
+    mask = jnp.tril(jnp.ones((c, c), bool))
 
     def step(state, xs):
         qi, ki, vi, cumi, toti = xs
@@ -74,11 +80,13 @@ def gla_chunked(
         d_q = jnp.exp(cumi)                                  # (B,H,c)
         # decay from position t (exclusive) to chunk end
         d_k = jnp.exp(toti - cumi)                           # (B,H,c)
-        # intra-chunk causal attention with decay ratio exp(cum_i - cum_j)
-        att = jnp.einsum("bhin,bhjn->bhij", qi, ki)          # (B,H,c,c)
-        ratio = jnp.exp(cumi[..., :, None] - cumi[..., None, :])
-        mask = jnp.tril(jnp.ones((c, c), bool))
-        att = jnp.where(mask, att * ratio, 0.0)
+        # intra-chunk causal attention with decay ratio exp(cum_i - cum_j),
+        # its exponent masked before exp: above the diagonal it is > 0 and
+        # may overflow, and inf there would make the gradient nan
+        att = jnp.einsum("bhin,bhjn->bhij", qi, ki)          # (B,hk,c,c)
+        ratio = jnp.exp(jnp.where(
+            mask, cumi[..., :, None] - cumi[..., None, :], -jnp.inf))
+        att = att * ratio                                    # (B,H,c,c)
         y_intra = jnp.einsum("bhij,bhjp->bhip", att, vi)
         # inter-chunk: carried state
         y_state = jnp.einsum("bhin,bhnp->bhip", qi * d_q[..., None], state)
@@ -91,7 +99,10 @@ def gla_chunked(
     qf = qc.astype(jnp.float32)
     kf = kc.astype(jnp.float32)
     vf = vc.astype(jnp.float32)
-    state, ys = jax.lax.scan(step, state0, (qf, kf, vf, cum, total))
+    # each chunk's (c, c) products are recomputed in the backward pass, not
+    # kept for all S / c chunks at once
+    state, ys = jax.lax.scan(jax.checkpoint(step), state0,
+                             (qf, kf, vf, cum, total))
     y = ys.transpose(1, 0, 3, 2, 4).reshape(b, s, h, p)[:, :s_orig]
     return y.astype(v.dtype), state
 
@@ -121,72 +132,120 @@ def _mamba_dims(cfg: ModelConfig):
 
 
 def init_mamba2(key, cfg: ModelConfig):
+    """Mamba-2's initialisation: per-head decay rates A ~ U[1, 16], steps
+    dt ~ exp U[log 1e-3, log 1e-1] held as ``dt_bias = softplus^-1(dt)``,
+    conv taps and bias ~ U(+-1/sqrt(W)) (a depthwise Conv1d's default).
+
+    The conv taps stay float32 with the block's other small parameters:
+    at |w| ~ 0.25 a bfloat16 tap rounds away updates under 1e-3, which at
+    the usual learning rates is most of them."""
     d = cfg.d_model
     d_inner, nh, n = _mamba_dims(cfg)
+    conv_dim = d_inner + 2 * n
     k1, k2, k3, k4 = jax.random.split(key, 4)
+    kw, kb = jax.random.split(k3)
+    ka, kt = jax.random.split(k4)
     dt = jnp.dtype(cfg.dtype)
     s = d ** -0.5
-    # in_proj emits [x (d_inner), z (d_inner), B (N), C (N), dt (nh)]
-    out_dim = 2 * d_inner + 2 * n + nh
+    bound = cfg.ssm_conv ** -0.5
+    step = jnp.exp(jax.random.uniform(
+        kt, (nh,), minval=math.log(1e-3), maxval=math.log(1e-1)))
+    # in_proj emits [z (d_inner), xBC (d_inner + 2N), dt (nh)]
     return {
         "norm": init_rmsnorm(d),
-        "in_proj": (jax.random.normal(k1, (d, out_dim)) * s).astype(dt),
-        "a_log": jnp.zeros((nh,), jnp.float32),
-        "dt_bias": jnp.full((nh,), -2.0, jnp.float32),
+        "in_proj": (jax.random.normal(k1, (d, d_inner + conv_dim + nh))
+                    * s).astype(dt),
+        "conv_w": jax.random.uniform(kw, (cfg.ssm_conv, conv_dim),
+                                     minval=-bound, maxval=bound),
+        "conv_b": jax.random.uniform(kb, (conv_dim,), minval=-bound,
+                                     maxval=bound),
+        "a_log": jnp.log(jax.random.uniform(ka, (nh,), minval=1.0,
+                                            maxval=16.0)),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
         "d_skip": jnp.ones((nh,), jnp.float32),
+        "gate_norm": init_rmsnorm(d_inner),
         "out_proj": (jax.random.normal(k2, (d_inner, d))
                      * d_inner ** -0.5).astype(dt),
     }
 
 
-def _mamba_project(p, cfg, x):
+def _causal_conv(p, xbc, window):
+    """Depthwise causal conv over time, then SiLU.  ``window`` holds the
+    W - 1 input rows before ``xbc`` (zeros at a sequence start); returns
+    (activations, the last W - 1 input rows)."""
+    s = xbc.shape[1]
+    xp = jnp.concatenate([window.astype(xbc.dtype), xbc], axis=1)
+    taps = p["conv_w"]
+    acc = p["conv_b"] + sum(xp[:, i:i + s].astype(jnp.float32) * taps[i]
+                            for i in range(taps.shape[0]))
+    return jax.nn.silu(acc).astype(xbc.dtype), xp[:, s:]
+
+
+def _mamba_mix(p, cfg: ModelConfig, x, state, ssd):
+    """The Mamba-2 mixer around its state-space core ``ssd``: pre-norm,
+    in_proj, causal conv over xBC, dt = softplus(dt + dt_bias), the SSD,
+    the D skip, y = RMSNorm(y * silu(z)) * w over d_inner, and out_proj.
+
+    ``ssd(C, B, x * dt, A * dt, ssm_state)`` -> (y, ssm_state), with C and
+    B as one shared head (B, S, 1, N).  Returns (out, new state)."""
     d_inner, nh, n = _mamba_dims(cfg)
-    h = rms_norm(p["norm"], x, cfg.norm_eps)
-    proj = jnp.einsum("bsd,de->bse", h, p["in_proj"])
-    proj = logical(proj, "batch", None, "ff")
-    xin, z, bmat, cmat, dt_raw = jnp.split(
-        proj, [d_inner, 2 * d_inner, 2 * d_inner + n, 2 * d_inner + 2 * n],
-        axis=-1)
-    b_, s_ = x.shape[0], x.shape[1]
-    xin = xin.reshape(b_, s_, nh, cfg.ssm_head_dim)
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
-    log_a = -jnp.exp(p["a_log"])[None, None, :] * dt       # (B,S,nh) <= 0
-    # B/C shared across heads (single group)
-    k = jnp.broadcast_to(bmat[:, :, None, :], (b_, s_, nh, n))
-    q = jnp.broadcast_to(cmat[:, :, None, :], (b_, s_, nh, n))
-    # discretised input: dt-scaled
-    v = xin * dt[..., None].astype(xin.dtype)
-    return q, k, v, log_a, xin, z
+    b_, s_ = x.shape[:2]
+    with jax.named_scope("mamba2"):
+        h = rms_norm(p["norm"], x, cfg.norm_eps)
+        proj = jnp.einsum("bsd,de->bse", h, p["in_proj"])
+        proj = logical(proj, "batch", None, "ff")
+        z, xbc, dt_raw = jnp.split(proj, [d_inner, 2 * d_inner + 2 * n],
+                                   axis=-1)
+        with jax.named_scope("conv"):
+            xbc, window = _causal_conv(p, xbc, state["conv"])
+        with jax.named_scope("ssd"):
+            xs, bmat, cmat = jnp.split(xbc, [d_inner, d_inner + n], axis=-1)
+            xs = xs.reshape(b_, s_, nh, cfg.ssm_head_dim).astype(jnp.float32)
+            dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
+            log_a = -jnp.exp(p["a_log"]) * dt                # (B,S,nh) <= 0
+            y, ssm_state = ssd(cmat[:, :, None], bmat[:, :, None],
+                               xs * dt[..., None], log_a, state["ssm"])
+            y = y + xs * p["d_skip"][:, None]
+        with jax.named_scope("gate_norm"):
+            y = y.reshape(b_, s_, d_inner) * jax.nn.silu(
+                z.astype(jnp.float32))
+            y = rms_norm(p["gate_norm"], y, cfg.norm_eps).astype(x.dtype)
+        out = jnp.einsum("bse,ed->bsd", y, p["out_proj"])
+        out = residual(cfg, x, logical(out, "batch", None, None))
+    return out, {"ssm": ssm_state, "conv": window}
 
 
-def mamba2(p, cfg: ModelConfig, x: jax.Array,
-           state: Optional[jax.Array] = None):
-    """Returns (out, new_state). state: (B, H, N, P)."""
-    d_inner, nh, n = _mamba_dims(cfg)
-    q, k, v, log_a, xin, z = _mamba_project(p, cfg, x)
-    y, new_state = gla_chunked(q, k, v, log_a, cfg.chunk, state)
-    y = y + xin * p["d_skip"][None, None, :, None].astype(xin.dtype)
-    y = y.reshape(x.shape[0], x.shape[1], d_inner)
-    y = y * jax.nn.silu(z)
-    out = jnp.einsum("bse,ed->bsd", y, p["out_proj"])
-    return x + logical(out, "batch", None, None), new_state
+def mamba2(p, cfg: ModelConfig, x: jax.Array, state=None):
+    """Returns (out, new_state); state: ``init_mamba2_state``'s, or None
+    at a sequence start."""
+    if state is None:
+        state = init_mamba2_state(cfg, x.shape[0])
+    return _mamba_mix(p, cfg, x, state,
+                      lambda q, k, v, la, st: gla_chunked(q, k, v, la,
+                                                          cfg.chunk, st))
 
 
-def mamba2_decode(p, cfg: ModelConfig, x: jax.Array, state: jax.Array):
-    """x: (B, 1, d). O(1) per-token state update."""
-    d_inner, nh, n = _mamba_dims(cfg)
-    q, k, v, log_a, xin, z = _mamba_project(p, cfg, x)
-    y, new_state = gla_decode_step(
-        q[:, 0], k[:, 0], v[:, 0], log_a[:, 0], state)
-    y = y[:, None] + xin * p["d_skip"][None, None, :, None].astype(xin.dtype)
-    y = y.reshape(x.shape[0], 1, d_inner) * jax.nn.silu(z)
-    out = jnp.einsum("bse,ed->bsd", y, p["out_proj"])
-    return x + logical(out, "batch", None, None), new_state
+def mamba2_decode(p, cfg: ModelConfig, x: jax.Array, state):
+    """x: (B, 1, d). O(1) per-token update of the SSM state and the conv
+    window."""
+    def one_step(q, k, v, la, st):
+        nh = v.shape[2]
+        q, k = (jnp.broadcast_to(a[:, 0], (a.shape[0], nh, a.shape[-1]))
+                for a in (q, k))
+        y, st = gla_decode_step(q, k, v[:, 0], la[:, 0], st)
+        return y[:, None], st
+    return _mamba_mix(p, cfg, x, state, one_step)
 
 
 def init_mamba2_state(cfg: ModelConfig, batch: int):
-    _, nh, n = _mamba_dims(cfg)
-    return jnp.zeros((batch, nh, n, cfg.ssm_head_dim), jnp.float32)
+    """The SSM state (B, H, N, P) f32 and the conv window: the last W - 1
+    rows of xBC (B, W - 1, d_inner + 2N)."""
+    d_inner, nh, n = _mamba_dims(cfg)
+    return {
+        "ssm": jnp.zeros((batch, nh, n, cfg.ssm_head_dim), jnp.float32),
+        "conv": jnp.zeros((batch, cfg.ssm_conv - 1, d_inner + 2 * n),
+                          jnp.dtype(cfg.dtype)),
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -247,7 +306,7 @@ def mlstm(p, cfg: ModelConfig, x: jax.Array,
     y = y / jnp.maximum(jnp.abs(n), 1.0)
     y = y.reshape(x.shape[0], x.shape[1], d)
     out = jnp.einsum("bsd,de->bse", y, p["out_proj"])
-    return x + logical(out, "batch", None, None), new_state
+    return residual(cfg, x, logical(out, "batch", None, None)), new_state
 
 
 def mlstm_decode(p, cfg: ModelConfig, x: jax.Array, state: jax.Array):
@@ -262,7 +321,7 @@ def mlstm_decode(p, cfg: ModelConfig, x: jax.Array, state: jax.Array):
     y, n = y_ext[..., :dh], y_ext[..., dh:]
     y = (y / jnp.maximum(jnp.abs(n), 1.0)).reshape(x.shape[0], 1, d)
     out = jnp.einsum("bsd,de->bse", y, p["out_proj"])
-    return x + logical(out, "batch", None, None), new_state
+    return residual(cfg, x, logical(out, "batch", None, None)), new_state
 
 
 def init_mlstm_state(cfg: ModelConfig, batch: int):
@@ -346,7 +405,7 @@ def slstm(p, cfg: ModelConfig, x: jax.Array, state=None):
         state = init_slstm_state(cfg, b)
     hs, new_state = _slstm_scan(z, i, f, o, state)
     out = jnp.einsum("bsd,de->bse", hs.astype(x.dtype), p["out_proj"])
-    return x + logical(out, "batch", None, None), new_state
+    return residual(cfg, x, logical(out, "batch", None, None)), new_state
 
 
 def init_slstm_state(cfg: ModelConfig, batch: int):

@@ -52,6 +52,7 @@ class TestArchSmoke:
             "musicgen_large": (48, 2048, 32, 32, 8192, 2048),
             "llama_3_2_vision_90b": (100, 8192, 64, 8, 28672, 128256),
             "zamba2_1_2b": (38, 2048, 32, 32, 8192, 32000),
+            "granite_4_0_h_micro": (40, 2048, 32, 8, 8192, 100352),
         }[arch]
         got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                cfg.d_ff, cfg.vocab)
@@ -64,6 +65,15 @@ class TestArchSmoke:
                 (128, 1, 8192)
         if arch == "zamba2_1_2b":
             assert cfg.ssm_state == 64
+        if arch == "granite_4_0_h_micro":
+            assert cfg.block_pattern == ("mamba2",) * 5 + ("attn",) + (
+                "mamba2",) * 4
+            assert (cfg.ssm_state, cfg.ssm_head_dim, cfg.ssm_expand,
+                    cfg.ssm_conv, cfg.chunk, cfg.d_head) == (
+                128, 64, 2, 4, 256, 64)
+            assert cfg.ssm_ffn and not cfg.rope
+            assert (cfg.embedding_multiplier, cfg.residual_multiplier,
+                    cfg.attention_multiplier) == (12.0, 0.22, 1 / 64)
 
     def test_train_step(self, arch):
         """One forward+backward+update on the reduced config: finite, moving."""
@@ -111,9 +121,25 @@ class TestArchSmoke:
         """long_500k runs iff the arch is sub-quadratic (SSM/hybrid)."""
         cfg = configs.get(arch)
         skip = shape_applicable(cfg, SHAPES["long_500k"])
-        if arch in ("xlstm_350m", "zamba2_1_2b"):
+        if arch in ("xlstm_350m", "zamba2_1_2b", "granite_4_0_h_micro"):
             assert skip is None
         else:
             assert skip is not None
         for s in ("train_4k", "prefill_32k", "decode_32k"):
             assert shape_applicable(cfg, SHAPES[s]) is None
+
+
+def test_lgd_trains_the_hybrid_through_a_refresh():
+    """Granite-4.0-H's SMOKE through ``train()`` with the LSH pipeline: the
+    refresh re-embeds the corpus with ``pooled_features`` through the
+    Mamba-2 and NoPE attention layers, and the draws use the new index."""
+    from repro.configs import granite_4_0_h_micro
+    from repro.launch.train import train
+    tr = train(granite_4_0_h_micro.SMOKE, steps=0, batch=2, seq=32,
+               corpus=64, lgd=True, refresh_every=2, multiprobe=28)
+    losses = tr.run(5)["losses"]
+    tr.finalize()
+    health = tr.sampler.health_summary()
+    assert np.all(np.isfinite(losses))
+    assert health["refreshes"] >= 1
+    assert health["refresh_failures"] == health["caught_errors"] == 0

@@ -53,6 +53,13 @@ CONFIGS = {
     "vision": tiny("vision", n_layers=4,
                    block_pattern=("attn", "cross_attn")),
     "audio": tiny("audio", n_kv_heads=4, frontend="embed_stub"),
+    # Granite-4.0-H's layer: Mamba-2 and NoPE attention layers, each with an
+    # MLP, and the embedding, residual and score multipliers
+    "granite4h": tiny("granite4h", n_layers=6,
+                      block_pattern=("mamba2", "mamba2", "attn"),
+                      ssm_state=16, ssm_head_dim=16, ssm_ffn=True,
+                      rope=False, embedding_multiplier=12.0,
+                      residual_multiplier=0.22, attention_multiplier=1 / 64),
 }
 
 
@@ -79,7 +86,7 @@ class TestForward:
         # at random init the LM loss should be near ln(vocab)
         assert abs(l - np.log(cfg.vocab)) < 1.5, l
 
-    @pytest.mark.parametrize("name", ["dense", "mamba", "zamba"])
+    @pytest.mark.parametrize("name", ["dense", "mamba", "zamba", "granite4h"])
     def test_scan_equals_unrolled(self, name):
         cfg = CONFIGS[name]
         params = init_params(KEY, cfg)
@@ -100,6 +107,18 @@ class TestForward:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-5)
 
+    def test_per_block_remat_matches_no_remat_on_the_hybrid(self):
+        """Each block of a multi-block pattern is rematerialised on its
+        own; its gradients equal the un-rematerialised ones."""
+        cfg = CONFIGS["granite4h"]
+        params = init_params(KEY, cfg)
+        batch = make_batch(cfg)
+        g1 = jax.grad(lambda p: loss(p, cfg.with_(remat=True), batch))(params)
+        g2 = jax.grad(lambda p: loss(p, cfg.with_(remat=False), batch))(params)
+        for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-6)
+
     def test_grads_nonzero_everywhere(self):
         """No dead parameters: every leaf gets gradient signal."""
         cfg = CONFIGS["zamba"]
@@ -117,7 +136,7 @@ class TestForward:
 
 class TestDecode:
     @pytest.mark.parametrize("name", ["dense", "mamba", "xlstm", "zamba",
-                                      "audio", "vision"])
+                                      "audio", "vision", "granite4h"])
     def test_decode_matches_forward(self, name):
         """prefill(prompt) then decode(next) == forward(prompt+next) last pos."""
         cfg = CONFIGS[name]
@@ -191,6 +210,84 @@ class TestGLACore:
                                    rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(np.asarray(state_chunk), np.asarray(state),
                                    rtol=1e-4, atol=1e-4)
+
+
+def _mamba2_per_token(p, cfg, x):
+    """Mamba-2 as published, one token at a time in float32: a conv window
+    of the last W inputs of xBC, the (H, N, P) state update
+    S = exp(A dt) S + dt B x^T, y = C S + D x, then the gated RMSNorm."""
+    d_inner = cfg.ssm_expand * cfg.d_model
+    nh, hd, n, w = d_inner // cfg.ssm_head_dim, cfg.ssm_head_dim, \
+        cfg.ssm_state, cfg.ssm_conv
+    b, s, _ = x.shape
+
+    def rms(v, scale):
+        return v / jnp.sqrt(jnp.mean(v * v, -1, keepdims=True)
+                            + cfg.norm_eps) * scale
+    proj = rms(x, p["norm"]["scale"]) @ p["in_proj"]
+    z, xbc, dt_raw = (proj[..., :d_inner], proj[..., d_inner:-nh],
+                      proj[..., -nh:])
+    window = jnp.zeros((b, w, xbc.shape[-1]))
+    state = jnp.zeros((b, nh, n, hd))
+    a = -jnp.exp(p["a_log"])
+    outs = []
+    for t in range(s):
+        window = jnp.concatenate([window[:, 1:], xbc[:, t:t + 1]], axis=1)
+        u = jax.nn.silu(jnp.einsum("bwc,wc->bc", window, p["conv_w"])
+                        + p["conv_b"])
+        xs = u[:, :d_inner].reshape(b, nh, hd)
+        bt, ct = u[:, d_inner:d_inner + n], u[:, d_inner + n:]
+        dt = jax.nn.softplus(dt_raw[:, t] + p["dt_bias"])        # (b, nh)
+        state = (jnp.exp(a * dt)[..., None, None] * state
+                 + dt[..., None, None] * bt[:, None, :, None]
+                 * xs[:, :, None, :])
+        y = jnp.einsum("bn,bhnp->bhp", ct, state) + p["d_skip"][:, None] * xs
+        g = y.reshape(b, d_inner) * jax.nn.silu(z[:, t])
+        outs.append(rms(g, p["gate_norm"]["scale"]) @ p["out_proj"])
+    return x + cfg.residual_multiplier * jnp.stack(outs, axis=1)
+
+
+class TestMamba2Block:
+    @pytest.mark.parametrize("s, chunk", [(37, 16), (32, 8)])
+    def test_block_equals_per_token_recurrence(self, s, chunk):
+        """The chunked block (conv as shifted sums, SSD by chunks) against
+        the per-token recurrence, at small size in float32."""
+        from repro.models.ssm import init_mamba2, mamba2
+        cfg = CONFIGS["granite4h"].with_(chunk=chunk)
+        p = init_mamba2(jax.random.PRNGKey(3), cfg)
+        x = jax.random.normal(jax.random.PRNGKey(4), (2, s, cfg.d_model))
+        got, state = mamba2(p, cfg, x)
+        want = _mamba2_per_token(p, cfg, x)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-4)
+        d_in = cfg.ssm_expand * cfg.d_model
+        assert state["conv"].shape == (2, cfg.ssm_conv - 1,
+                                       d_in + 2 * cfg.ssm_state)
+
+    def test_init_spreads_the_decays(self):
+        """A = exp(a_log) in [1, 16] and dt = softplus(dt_bias) in
+        [1e-3, 1e-1], different for every head."""
+        from repro.models.ssm import init_mamba2
+        cfg = CONFIGS["granite4h"].with_(d_model=512, ssm_head_dim=16)
+        p = init_mamba2(jax.random.PRNGKey(5), cfg)
+        a = np.exp(np.asarray(p["a_log"]))
+        dt = np.asarray(jax.nn.softplus(p["dt_bias"]))
+        assert a.shape == dt.shape == (64,)
+        assert 1.0 <= a.min() and a.max() <= 16.0 and a.std() > 2.0
+        assert 1e-3 * 0.999 <= dt.min() and dt.max() <= 1e-1 * 1.001
+        assert len(np.unique(a)) == 64
+
+    def test_long_chunks_keep_gradients_finite(self):
+        """Fast decays over a 256-step chunk: exp of the unmasked decay
+        ratio would overflow; the gradient stays finite."""
+        b, s, h, n, p = 1, 256, 2, 4, 4
+        kq, kk, kv = jax.random.split(jax.random.PRNGKey(6), 3)
+        q = jax.random.normal(kq, (b, s, 1, n))
+        k = jax.random.normal(kk, (b, s, 1, n))
+        v = jax.random.normal(kv, (b, s, h, p))
+        log_a = jnp.full((b, s, h), -1.6)
+        g = jax.grad(lambda v: jnp.sum(gla_chunked(q, k, v, log_a, 256)[0]))(v)
+        assert bool(jnp.all(jnp.isfinite(g)))
 
 
 class TestMoE:
