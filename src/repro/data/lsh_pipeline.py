@@ -553,10 +553,10 @@ class LSHSampledPipeline:
         if not self._params_aware:
             with self.spans("index/embed"):
                 return self.feature_fn(chunk)
-        from repro.models.lm import embed_attention
+        from repro.models.lm import attention_path
         # the attention path ``pooled_features`` takes for these params
         # and rows, so a trace shows which embed calls ran the kernel
-        attn = embed_attention(params, chunk.shape[1])
+        attn = attention_path(params, chunk.shape[1])
         with self.spans("index/embed", attn=attn):
             leaves = jax.tree.leaves(params)
             sh = getattr(leaves[0], "sharding", None) if leaves else None

@@ -1,11 +1,11 @@
 """Jit'd public wrappers: dispatch between the Pallas kernel and the oracle.
 
 The model code calls these with ``use_pallas`` from
-``ModelConfig.attn_impl == "pallas"``.  The kernel's one user is the LGD
-refresh embed (``repro.models.lm.pooled_features``), which takes it on a
-single TPU device; training keeps the chunked XLA attention, since the
-kernel has no backward.  CPU smoke tests run the oracle (XLA:CPU) and
-the kernel tests run interpret mode.
+``ModelConfig.attn_impl == "pallas"``, which ``repro.models.lm.forward``
+sets on a single TPU device (``attention_path``): the loss and its
+gradient, through the kernel's dQ and dK/dV backward, and the LGD refresh
+embed, which runs the forward kernel alone.  CPU smoke tests run the
+oracle (XLA:CPU) and the kernel tests run interpret mode.
 """
 
 from __future__ import annotations

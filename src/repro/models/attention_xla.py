@@ -3,10 +3,11 @@
 This is the XLA twin of the Pallas flash kernel: on the dry-run host
 (and any non-TPU backend) it gives the same O(S * block) activation
 memory so 32k-token prefill/train cells fit HBM, while keeping the HLO
-analyzable for the roofline accounting.  On TPU the Pallas kernel
-replaces it only in the forward-only refresh embed
-(``repro.models.lm.pooled_features``); training keeps this scan, since
-the kernel has no backward.
+analyzable for the roofline accounting.  On one TPU device the kernel and
+its backward replace it in training and in the refresh embed
+(``repro.models.lm.attention_path``); this scan runs on the CPU, in
+mesh-partitioned programs and where no kernel block divides the
+sequence.
 
 Schedule: outer lax.scan over query blocks; each step attends its block
 to the full (masked) KV — softmax in f32 with the usual max-subtraction.

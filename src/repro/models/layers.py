@@ -116,14 +116,17 @@ def attention(
         if kv is None and cfg.rope:   # self attention with rope
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
-        if impl == "chunked":
-            from .attention_xla import chunked_gqa_attention
-            out = chunked_gqa_attention(
-                q, k, v, causal=causal and kv is None,
-                block_q=cfg.attn_block_q)
-        else:
-            out = gqa_attention(q, k, v, causal=causal and kv is None,
-                                use_pallas=impl == "pallas")
+        # the core alone, between the projections and ``wo``: its device
+        # ops carry the scope in the trace (``train_attention_device_ms``)
+        with jax.named_scope("attention"):
+            if impl == "chunked":
+                from .attention_xla import chunked_gqa_attention
+                out = chunked_gqa_attention(
+                    q, k, v, causal=causal and kv is None,
+                    block_q=cfg.attn_block_q)
+            else:
+                out = gqa_attention(q, k, v, causal=causal and kv is None,
+                                    use_pallas=impl == "pallas")
         new_cache = None
         if kv is None and cache is not None:
             s = x.shape[1]

@@ -283,6 +283,11 @@ def _scan_blocks(params, cfg: ModelConfig, x, positions, image_mem,
 
 
 def forward(params, cfg: ModelConfig, batch) -> jax.Array:
+    """Final hidden states.  Self-attention runs through the causal flash
+    kernel where ``forward_attention`` says so (training differentiates it
+    through the kernel's backward), else ``cfg.attn_impl``."""
+    if forward_attention(params, cfg, batch) == "flash":
+        cfg = cfg.with_(attn_impl="pallas")
     x, image_mem, positions = _inputs(params, cfg, batch)
     x, _ = _scan_blocks(params, cfg, x, positions, image_mem, None, False)
     return x
@@ -304,18 +309,25 @@ def logits(params, cfg: ModelConfig, batch) -> jax.Array:
 # LGD feature-extraction hooks (paper Sec. 3.2: the BERT recipe)
 # ---------------------------------------------------------------------------
 
-def embed_attention(params, seq: int) -> str:
-    """The self-attention path of ``pooled_features`` for ``params`` and
-    rows of ``seq`` tokens: ``"flash"``, the causal Pallas kernel, where
-    the backend is TPU, the params live on one device (Mosaic kernels do
-    not run in a mesh-partitioned program) and the kernel's blocks divide
-    ``seq``; else ``"chunked"``, the XLA scan that training runs.  Reads
-    only avals, so it holds at trace time too."""
+def attention_path(params, seq: int) -> str:
+    """The self-attention path of ``forward`` (the loss and its gradient,
+    the LGD refresh embed) for ``params`` and rows of ``seq`` tokens:
+    ``"flash"``, the causal Pallas kernel and its backward, where the
+    backend is TPU, the params live on one device (Mosaic kernels do not
+    run in a mesh-partitioned program) and the kernel's blocks divide
+    ``seq``; else ``"chunked"``, the XLA scan.  Reads only avals, so it
+    holds at trace time too."""
     if (kernels.default_use_pallas() and default_blocks(seq) is not None
             and all(jax.typeof(x).sharding.mesh.size <= 1
                     for x in jax.tree.leaves(params))):
         return "flash"
     return "chunked"
+
+
+def forward_attention(params, cfg: ModelConfig, batch) -> str:
+    """``attention_path`` of ``forward(params, cfg, batch)``."""
+    rows = batch["embeds"] if cfg.frontend == "embed_stub" else batch["tokens"]
+    return attention_path(params, rows.shape[1])
 
 
 def pooled_features(params, cfg: ModelConfig, batch) -> jax.Array:
@@ -324,12 +336,9 @@ def pooled_features(params, cfg: ModelConfig, batch) -> jax.Array:
     The paper hashes each example's pooled last-layer representation into
     the LSH index; this is the model-side half of that contract (the
     pipeline half is ``repro.data.LSHSampledPipeline``).  Nothing
-    differentiates it, so its self-attention may take the forward-only
-    flash kernel (``embed_attention``).
+    differentiates it, so where its self-attention takes the flash kernel
+    (``attention_path``) it runs the kernel's forward alone.
     """
-    rows = batch["embeds"] if cfg.frontend == "embed_stub" else batch["tokens"]
-    if embed_attention(params, rows.shape[1]) == "flash":
-        cfg = cfg.with_(attn_impl="pallas")
     h = forward(params, cfg, batch)
     return jnp.mean(h.astype(jnp.float32), axis=1)
 

@@ -27,11 +27,12 @@ has read the step's ``ok`` flag (``bool(ok)``) and, with a sampler, its
 loss (``float(l)``), so the draw waits for the step instead of
 overlapping it.  Each step opens profiler spans (``repro.spans``):
 ``StepTraceAnnotation("train")`` around the iteration, ``train/dispatch``
-around the step's dispatch, ``train/sync`` around each read of its
-results and ``train/draw`` around each batch draw (with the sampler's
-own ``lgd/...`` and ``index/...`` spans inside).  ``span_totals()`` sums
-their seconds and calls without a device sync; ``data_seconds`` is the
-``train/draw`` total.
+around the step's dispatch (with the model's loss, ``attn=`` names its
+attention path, ``"flash"`` or ``"chunked"``), ``train/sync`` around
+each read of its results and ``train/draw`` around each batch draw (with
+the sampler's own ``lgd/...`` and ``index/...`` spans inside).
+``span_totals()`` sums their seconds and calls without a device sync;
+``data_seconds`` is the ``train/draw`` total.
 
 ADAPTIVE OPTIMIZERS under LGD: the sampler composes with ANY
 ``repro.optim`` optimiser (Adam, AdaGrad, momentum-SGD, ...) because
@@ -77,6 +78,7 @@ import jax.numpy as jnp
 from jax.profiler import StepTraceAnnotation
 
 from repro.models import ModelConfig, loss as lm_loss
+from repro.models.lm import forward_attention
 from repro.optim import apply_updates
 from repro.spans import Spans, Totals, merged
 from . import checkpoint as ckpt
@@ -191,6 +193,8 @@ class Trainer:
         self.rollbacks = 0          # checkpoint rollbacks taken
         self._bad_streak = 0        # consecutive skipped steps
         self.spans = Spans()        # train/... host spans (module docstring)
+        # the model's own loss: its dispatch span names the attention path
+        self._model_loss = loss_fn is None
         loss_fn = loss_fn or (lambda p, b: lm_loss(p, cfg, b))
 
         clip = tcfg.grad_clip
@@ -407,7 +411,10 @@ class Trainer:
         while self.step < target:
             with StepTraceAnnotation("train", step_num=self.step):
                 t0 = time.perf_counter()
-                with self.spans("train/dispatch", step=self.step):
+                attn = ({"attn": forward_attention(
+                    self.params, self.cfg, next_batch)}
+                    if self._model_loss else {})
+                with self.spans("train/dispatch", step=self.step, **attn):
                     self.params, self.opt_state, l, gnorm, ef, ok = \
                         self._step_fn(self.params, self.opt_state,
                                       next_batch,

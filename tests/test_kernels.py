@@ -67,6 +67,21 @@ def _qkv(key, b, hkv, g, s, d, dtype=jnp.float32):
     return q, k, v
 
 
+def _pallas_outputs(jaxpr):
+    """(shape, dtype) of each output of every ``pallas_call`` in ``jaxpr``
+    and the jaxprs nested in it, in order."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append([(v.aval.shape, v.aval.dtype) for v in eqn.outvars])
+            continue
+        for p in eqn.params.values():
+            sub = getattr(p, "jaxpr", p)
+            if hasattr(sub, "eqns"):
+                out += _pallas_outputs(sub)
+    return out
+
+
 class TestFlashAttention:
     @pytest.mark.parametrize("b,hkv,g,s,d,bq,bk", [
         (1, 1, 1, 128, 64, 64, 64),
@@ -111,6 +126,51 @@ class TestFlashAttention:
             np.asarray(got, np.float32), np.asarray(want, np.float32),
             rtol=3e-2, atol=3e-2,
         )
+
+    @pytest.mark.parametrize("dtype, tol", [
+        (jnp.float32, 1e-5),
+        (jnp.bfloat16, 2e-2),    # the gradients come out in bf16
+    ])
+    @pytest.mark.parametrize("bq,bk", [(128, 128), (256, 128), (128, 256)])
+    @pytest.mark.parametrize("d", [64, 128])
+    @pytest.mark.parametrize("g", [1, 4])
+    def test_gradients_match_ref(self, g, d, bq, bk, dtype, tol):
+        """dQ, dK and dV of the custom-VJP kernel (P recomputed from the
+        saved log-sum-exp) equal the oracle's autodiff gradients, causal,
+        at S = 512 across blocks that cross the diagonal both ways."""
+        q, k, v = _qkv(jax.random.fold_in(KEY, g * d), 1, 2, g, 512, d, dtype)
+        w = jax.random.normal(jax.random.fold_in(KEY, 1), q.shape)
+
+        def through(attend):
+            return lambda q, k, v: jnp.sum(
+                attend(q, k, v).astype(jnp.float32) * w)
+        got = jax.grad(through(lambda q, k, v: flash_attention_pallas(
+            q, k, v, causal=True, block_q=bq, block_k=bk, interpret=True)),
+            argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(through(lambda q, k, v: attention_ref(
+            q, k, v, causal=True)), argnums=(0, 1, 2))(q, k, v)
+        for name, a, b in zip("qkv", got, want):
+            assert a.dtype == dtype, name
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            assert np.abs(a - b).max() <= tol * np.abs(b).max(), name
+
+    def test_only_the_differentiated_forward_saves_the_lse(self):
+        """The primal call runs the forward kernel alone, with one output;
+        the gradient's forward adds the f32 log-sum-exp, and the dK/dV and
+        dQ kernels follow."""
+        q, k, v = _qkv(KEY, 1, 2, 4, 256, 64)
+
+        def attend(q, k, v):
+            return flash_attention_pallas(q, k, v, block_q=128,
+                                          block_k=128, interpret=True)
+        primal = _pallas_outputs(jax.make_jaxpr(attend)(q, k, v).jaxpr)
+        assert primal == [[((2, 1024, 64), jnp.float32)]]
+        vjp = jax.make_jaxpr(lambda q, k, v: jax.vjp(attend, q, k, v)[1](q))
+        assert _pallas_outputs(vjp(q, k, v).jaxpr) == [
+            [((2, 1024, 64), jnp.float32), ((2, 1024, 128), jnp.float32)],
+            [((2, 256, 64), jnp.float32)] * 2,        # dK, dV
+            [((2, 1024, 64), jnp.float32)],           # dQ
+        ]
 
     def test_default_blocks(self):
         from repro.kernels.flash_attention.kernel import default_blocks

@@ -366,9 +366,10 @@ class TestChunkedAttention:
 
 
 class TestEmbedAttention:
-    """``pooled_features`` (the LGD refresh embed) runs self-attention
-    through the flash kernel where ``embed_attention`` says so: TPU, params
-    on one device, kernel blocks dividing the sequence."""
+    """``forward`` (under the loss and its gradient, and ``pooled_features``,
+    the LGD refresh embed) runs self-attention through the flash kernel
+    where ``attention_path`` says so: TPU, params on one device, kernel
+    blocks dividing the sequence."""
 
     @pytest.fixture
     def tpu_path(self, monkeypatch):
@@ -388,26 +389,26 @@ class TestEmbedAttention:
         gqa_attention.clear_cache()
 
     def test_cpu_takes_the_chunked_path(self):
-        from repro.models.lm import embed_attention
+        from repro.models.lm import attention_path
         params = init_params(KEY, tiny("embed_cpu"))
-        assert embed_attention(params, 128) == "chunked"
+        assert attention_path(params, 128) == "chunked"
 
     def test_rule(self, tpu_path):
         from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec
-        from repro.models.lm import embed_attention
+        from repro.models.lm import attention_path
         cfg = tiny("embed_rule")
         params = init_params(KEY, cfg)
-        assert embed_attention(params, 128) == "flash"
-        assert embed_attention(params, 4096) == "flash"
-        assert embed_attention(params, 100) == "chunked"   # no block fits
+        assert attention_path(params, 128) == "flash"
+        assert attention_path(params, 4096) == "flash"
+        assert attention_path(params, 100) == "chunked"   # no block fits
         meshed = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=NamedSharding(
                 AbstractMesh((4,), ("data",)), PartitionSpec())), params)
-        assert embed_attention(meshed, 128) == "chunked"   # four devices
+        assert attention_path(meshed, 128) == "chunked"   # four devices
 
     def test_pooled_features_flash_matches_chunked(self, tpu_path):
         """At smoke width in bf16, the kernel's features equal the chunked
-        scan's within bf16 rounding, and the loss keeps the scan."""
+        scan's within bf16 rounding, and the loss takes the kernel too."""
         import repro.kernels
         from repro.configs import granite_3_8b
         from repro.models import pooled_features
@@ -415,8 +416,8 @@ class TestEmbedAttention:
         params = init_params(KEY, cfg)
         batch = make_batch(cfg, b=2, s=128)
         got = jax.jit(lambda p, b: pooled_features(p, cfg, b))(params, batch)
-        loss_text = jax.jit(lambda p, b: loss(p, cfg, b)).lower(
-            params, batch).as_text()
+        loss_jaxpr = str(jax.make_jaxpr(lambda p, b: loss(p, cfg, b))(
+            params, batch))
         repro.kernels.default_use_pallas = lambda: False
         want = jax.jit(lambda p, b: pooled_features(p, cfg, b))(params, batch)
         got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
@@ -424,4 +425,55 @@ class TestEmbedAttention:
             want, axis=1)
         assert gap.max() < 2e-2, gap
         assert not np.array_equal(got, want)    # the kernel ran
-        assert "pallas" not in loss_text
+        assert "pallas_call" in loss_jaxpr
+
+    @pytest.mark.parametrize("arch, dtype, tol", [
+        ("granite_3_8b", "float32", 1e-4),
+        ("granite_3_8b", "bfloat16", 3e-2),
+        # NoPE attention inside the hybrid's ordered remat
+        ("granite_4_0_h_micro", "float32", 1e-4),
+    ])
+    def test_loss_and_grad_flash_match_chunked(self, tpu_path, arch, dtype,
+                                               tol):
+        """The loss and its gradient through the kernel's backward equal
+        the chunked scan's at smoke width: to f32 round-off, and in bf16
+        within bf16 rounding (each path then sits about 1.5% from the f32
+        gradient)."""
+        import importlib
+        import repro.kernels
+        cfg = importlib.import_module(f"repro.configs.{arch}").SMOKE.with_(
+            dtype=dtype)
+        params = init_params(KEY, cfg)
+        batch = make_batch(cfg, b=2, s=256)
+        fn = jax.value_and_grad(lambda p, b: loss(p, cfg, b))
+        assert "pallas_call" in str(jax.make_jaxpr(fn)(params, batch))
+        got = jax.jit(fn)(params, batch)
+        repro.kernels.default_use_pallas = lambda: False
+        want = jax.jit(fn)(params, batch)
+        assert abs(float(got[0]) - float(want[0])) <= tol * abs(
+            float(want[0]))
+        for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got[1]),
+                                jax.tree.leaves(want[1])):
+            g, w = np.asarray(g, np.float64), np.asarray(w, np.float64)
+            gap = np.linalg.norm(g - w) / max(np.linalg.norm(w), 1e-12)
+            assert gap < tol, (jax.tree_util.keystr(path), gap)
+
+    def test_train_dispatch_names_the_attention_path(self, monkeypatch):
+        """The trainer's ``train/dispatch`` span carries ``attn=``, the
+        path of the model's loss: the chunked scan on this CPU host."""
+        from repro.optim import Adam
+        from repro.spans import Spans
+        from repro.train import Trainer, TrainerConfig
+        seen = []
+        real = Spans.__call__
+
+        def record(self, name, **meta):
+            seen.append((name, meta))
+            return real(self, name, **meta)
+        monkeypatch.setattr(Spans, "__call__", record)
+        cfg = tiny("dispatch_attn")
+        batches = iter([make_batch(cfg, b=2, s=16)] * 2)
+        Trainer(cfg, init_params(KEY, cfg), Adam(lr=1e-3), batches,
+                TrainerConfig(ckpt_dir=None)).run(2)
+        attn = [m.get("attn") for n, m in seen if n == "train/dispatch"]
+        assert attn == ["chunked", "chunked"]

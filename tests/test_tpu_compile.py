@@ -14,6 +14,7 @@ imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -141,11 +142,17 @@ def _granite_one_chip(dev):
     return cfg, params, tokens
 
 
+def _kernel_results(text):
+    """The result type of each ``tpu_custom_call`` in a compiled program."""
+    return re.findall(r"= (\(.*?\)|\S+) custom-call\([^\n]*"
+                      r'custom_call_target="tpu_custom_call"', text)
+
+
 @pytest.mark.parametrize("program", ["refresh_embed", "loss_and_grad"])
 def test_granite_attention_path_on_v5e(one_chip, monkeypatch, program):
     """On one TPU device the refresh embed runs its attention through the
     flash kernel and still compiles as ``jit_refresh_embed``; the loss
-    and its gradient keep the chunked scan (the kernel has no backward)."""
+    and its gradient run it too, with its dQ and dK/dV kernels."""
     import repro.kernels
     from repro.data import mean_pool_feature_fn
     from repro.models import loss
@@ -159,6 +166,61 @@ def test_granite_attention_path_on_v5e(one_chip, monkeypatch, program):
     text = lowered.compile().as_text()
     if program == "refresh_embed":
         assert text.startswith("HloModule jit_refresh_embed")
-        assert "tpu_custom_call" in text
-    else:
-        assert "tpu_custom_call" not in text
+    assert "tpu_custom_call" in text
+
+
+def test_refresh_embed_runs_the_primal_kernel_on_v5e(one_chip, monkeypatch):
+    """Nothing differentiates the refresh embed, so its one kernel is the
+    forward alone: a single bf16 result, no log-sum-exp output, as before
+    the kernel had a backward.  The gradient's forward adds the f32
+    log-sum-exp (B*Hkv, G*S, 128)."""
+    import repro.kernels
+    from repro.data import mean_pool_feature_fn
+    from repro.models import loss
+    monkeypatch.setattr(repro.kernels, "default_use_pallas", lambda: True)
+    cfg, params, tokens = _granite_one_chip(one_chip)
+    embed = _kernel_results(mean_pool_feature_fn(cfg).lower(
+        params, tokens).compile().as_text())
+    assert len(embed) == 1 and embed[0].startswith("bf16[16,16384,128]"), \
+        embed
+    grad = _kernel_results(jax.jit(
+        lambda p, b: jax.value_and_grad(loss)(p, cfg, b)).lower(
+            params, {"tokens": tokens, "targets": tokens}).compile().as_text())
+    assert any("f32[16,16384,128]" in r for r in grad), grad
+
+
+# the parent program's step (chunked scan): 7,979,147,264 B of arguments
+# and 3,274,177,024 B of temporaries
+HYBRID_ARGS, HYBRID_TEMPS = 7_979_147_264, 3_274_177_024
+
+
+def test_hybrid_train_step_on_v5e(one_chip, monkeypatch):
+    """Granite-4.0-H-Micro's one-chip period at seq 8192: the trainer's step
+    (loss and gradient, clip, Adam, donation) runs its NoPE attention layer
+    through the flash kernel and its backward (D = 64), and needs no more
+    memory than the chunked scan's step."""
+    import repro.kernels
+    from repro.configs import granite_4_0_h_micro
+    from repro.models import init_params
+    from repro.optim import Adam
+    from repro.train import Trainer, TrainerConfig
+    monkeypatch.setattr(repro.kernels, "default_use_pallas", lambda: True)
+    cfg = granite_4_0_h_micro.ONE_CHIP
+    adam = Adam(lr=1e-3)
+    step = Trainer(cfg, {}, adam, iter([]), TrainerConfig(ckpt_dir=None),
+                   resume=False)._step_fn
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    compiled = step.lower(params, on_chip(jax.eval_shape(adam.init, params)),
+                          {"tokens": tokens, "targets": tokens},
+                          None).compile()
+    results = _kernel_results(compiled.as_text())
+    assert any("f32[8,32768,128]" in r for r in results), results
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes <= HYBRID_ARGS
+    assert mem.temp_size_in_bytes <= HYBRID_TEMPS
